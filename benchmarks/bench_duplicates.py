@@ -14,15 +14,17 @@ def main() -> None:
     ap.add_argument("--max-dup-log2", type=int, default=6)
     args = ap.parse_args()
 
-    import jax
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     import jax.numpy as jnp
     import numpy as np
 
     from benchmarks.common import emit, time_fn
-    from repro.core.table import DistributedHashTable
+    from repro.core.table import DistributedHashTable, table_mesh
 
-    d = len(jax.devices())
-    mesh = jax.make_mesh((d,), ("d",))
+    mesh = table_mesh()
     n = args.keys
     rng = np.random.default_rng(1)
 

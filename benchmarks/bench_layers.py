@@ -20,32 +20,6 @@ import argparse
 import json
 
 
-def _count_all_to_all(closed_jaxpr) -> int:
-    """Occurrences of the all_to_all primitive anywhere in a nested jaxpr."""
-    import jax.core as jcore
-
-    def subs(v):
-        if isinstance(v, jcore.ClosedJaxpr):
-            yield v.jaxpr
-        elif isinstance(v, jcore.Jaxpr):
-            yield v
-        elif isinstance(v, (tuple, list)):
-            for x in v:
-                yield from subs(x)
-
-    def walk(jaxpr):
-        n = 0
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "all_to_all":
-                n += 1
-            for v in eqn.params.values():
-                for sub in subs(v):
-                    n += walk(sub)
-        return n
-
-    return walk(closed_jaxpr.jaxpr)
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--keys", type=int, default=1 << 17)
@@ -55,6 +29,10 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true", help="~30s CI smoke run")
     ap.add_argument("--json", type=str, default=None, help="write rows to PATH")
     args = ap.parse_args()
+
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.smoke:
         args.keys = min(args.keys, 1 << 14)
@@ -67,11 +45,11 @@ def main() -> None:
     import numpy as np
 
     from benchmarks.common import emit, time_fn
-    from repro.core.table import DistributedHashTable
+    from repro.core.table import DistributedHashTable, table_mesh
 
     depths = [int(x) for x in args.depths.split(",")]
     d = len(jax.devices())
-    mesh = jax.make_mesh((d,), ("d",))
+    mesh = table_mesh()
     n, nq, batch = args.keys, args.queries, args.insert_batch
     rng = np.random.default_rng(7)
 
@@ -164,6 +142,7 @@ def main() -> None:
     # collective count must not grow with the delta depth.
     if args.smoke and deepest > 0:
         from repro.core import plans
+        from repro.obs.profiling import count_primitive
 
         table, by_depth = states_by_mode["fused"]
         a2a = {}
@@ -173,7 +152,7 @@ def main() -> None:
                     table, s, q, out_capacity=1024, seg_capacity=1024
                 )
             )(by_depth[depth], queries)
-            a2a[depth] = _count_all_to_all(jx)
+            a2a[depth] = count_primitive(jx.jaxpr, "all_to_all")
         assert a2a[deepest] == a2a[0], (
             f"fused routing regressed: depth-{deepest} retrieve traces "
             f"{a2a[deepest]} all_to_alls vs {a2a[0]} at depth 0"

@@ -12,6 +12,10 @@ def main() -> None:
     ap.add_argument("--keys", type=int, default=1 << 19)
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -22,9 +26,10 @@ def main() -> None:
     from repro.core import exchange, hashing, multi_hashgraph, partition
     from repro.core.hashgraph import EMPTY_KEY
     from repro.core import hashgraph
+    from repro.core.table import table_mesh
 
     d = len(jax.devices())
-    mesh = jax.make_mesh((d,), ("d",))
+    mesh = table_mesh()
     ax = ("d",)
     n = args.keys
     hr = n

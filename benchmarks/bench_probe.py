@@ -35,6 +35,10 @@ def main() -> None:
     ap.add_argument("--json", type=str, default=None, help="write rows to PATH")
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     if args.smoke:
         args.keys = min(args.keys, 1 << 14)
         args.queries = min(args.queries, 1 << 12)
@@ -46,12 +50,12 @@ def main() -> None:
 
     from benchmarks.common import emit, time_fn
     from repro.core.schema import TableSchema, pack_u64
-    from repro.core.table import DistributedHashTable
+    from repro.core.table import DistributedHashTable, table_mesh
 
     depths = [int(x) for x in args.depths.split(",")]
     deepest = max(depths)
     d = len(jax.devices())
-    mesh = jax.make_mesh((d,), ("d",))
+    mesh = table_mesh()
     n = (args.keys // d) * d
     nq = args.queries
     rng = np.random.default_rng(11)

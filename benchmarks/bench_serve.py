@@ -68,6 +68,10 @@ def main() -> None:
     ap.add_argument("--slo-ms", type=float, default=50.0, help="goodput latency budget")
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     if args.open_loop:
         _open_loop(args)
         return
@@ -84,11 +88,11 @@ def main() -> None:
 
     from benchmarks.common import emit, time_fn, write_bench_json
     from repro.core import maintenance
-    from repro.core.table import DistributedHashTable
+    from repro.core.table import DistributedHashTable, table_mesh
     from repro.serve_table import CompactionPolicy, MicroBatcher, TableServer
 
     d = len(jax.devices())
-    mesh = jax.make_mesh((d,), ("d",))
+    mesh = table_mesh()
     n = args.keys
     rng = np.random.default_rng(11)
     keys = rng.integers(0, n, size=n, dtype=np.uint32)
@@ -285,7 +289,7 @@ def _open_loop(args) -> None:
 
     write_bucket = max(8, d)
     table = DistributedHashTable(
-        jax.make_mesh((d,), ("d",)),
+        table_mesh(),
         ("d",),
         hash_range=n,
         capacity_slack=2.0,

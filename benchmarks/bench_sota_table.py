@@ -23,15 +23,19 @@ def main() -> None:
     ap.add_argument("--keys", type=int, default=1 << 19)
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from benchmarks.common import emit, time_fn
-    from repro.core.table import DistributedHashTable
+    from repro.core.table import DistributedHashTable, table_mesh
 
     d = len(jax.devices())
-    mesh = jax.make_mesh((d,), ("d",))
+    mesh = table_mesh()
     n = args.keys
     rng = np.random.default_rng(4)
     keys = jnp.asarray(rng.integers(0, n, size=n, dtype=np.uint32))

@@ -17,15 +17,17 @@ def main() -> None:
     ap.add_argument("--max-depth", type=int, default=8)
     args = ap.parse_args()
 
-    import jax
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     import jax.numpy as jnp
     import numpy as np
 
     from benchmarks.common import emit, time_fn
-    from repro.core.table import DistributedHashTable
+    from repro.core.table import DistributedHashTable, table_mesh
 
-    d = len(jax.devices())
-    mesh = jax.make_mesh((d,), ("d",))
+    mesh = table_mesh()
     n, batch = args.keys, args.insert_batch
     rng = np.random.default_rng(2)
 

@@ -13,6 +13,10 @@ def main() -> None:
     ap.add_argument("--keys-per-device", type=int, default=1 << 18)
     ap.add_argument("--devices", type=int, default=0, help="0 = use all present")
     args = ap.parse_args()
+
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.devices and "XLA_FLAGS" not in os.environ:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices}"
@@ -23,11 +27,11 @@ def main() -> None:
     import numpy as np
 
     from benchmarks.common import emit, time_fn
-    from repro.core.table import DistributedHashTable
+    from repro.core.table import DistributedHashTable, table_mesh
 
     d = len(jax.devices())
     n = args.keys_per_device * d
-    mesh = jax.make_mesh((d,), ("d",))
+    mesh = table_mesh()
     table = DistributedHashTable(mesh, ("d",), hash_range=n)
     rng = np.random.default_rng(0)
 

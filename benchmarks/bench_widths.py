@@ -15,16 +15,20 @@ def main() -> None:
     ap.add_argument("--dup", type=int, default=4, help="average key multiplicity")
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from benchmarks.common import emit, time_fn
     from repro.core.schema import TableSchema, pack_u64
-    from repro.core.table import DistributedHashTable
+    from repro.core.table import DistributedHashTable, table_mesh
 
     d = len(jax.devices())
-    mesh = jax.make_mesh((d,), ("d",))
+    mesh = table_mesh()
     n = (args.keys // d) * d
     rng = np.random.default_rng(1)
     distinct = max(1, n // args.dup)

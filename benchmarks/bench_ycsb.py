@@ -54,6 +54,10 @@ def main() -> None:
     ap.add_argument("--json", type=str, default="BENCH_ycsb.json")
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     if args.smoke:
         args.keys = min(args.keys, 1 << 10)
         args.ops = min(args.ops, 600)
@@ -66,7 +70,7 @@ def main() -> None:
     from benchmarks.common import assert_clean_run, emit, write_bench_json
     from repro.cache.workload import WORKLOADS, YCSBWorkload, key_of
     from repro.core import plans
-    from repro.core.table import DistributedHashTable
+    from repro.core.table import DistributedHashTable, table_mesh
     from repro.serve_table import (
         AsyncFrontend,
         CompactionPolicy,
@@ -156,7 +160,7 @@ def main() -> None:
 
     # ---- table + server + AOT warmup ----------------------------------------
     table = DistributedHashTable(
-        jax.make_mesh((d,), ("d",)),
+        table_mesh(),
         ("d",),
         hash_range=max(n, 1024),
         capacity_slack=2.0,

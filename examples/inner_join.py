@@ -19,6 +19,7 @@ from repro.core.table import (
     DistributedHashTable,
     join_to_pairs,
     retrieval_to_lists,
+    table_mesh,
 )
 
 
@@ -36,7 +37,7 @@ def main() -> None:
     rng.shuffle(s_keys)
 
     d = len(jax.devices())
-    mesh = jax.make_mesh((d,), ("d",))
+    mesh = table_mesh()
     table = DistributedHashTable(mesh, ("d",), hash_range=n_r)
     # values = R row ids ride through the exchange for the join payload
     state = table.build(

@@ -10,16 +10,14 @@ workload drives the same machinery at the end.
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         PYTHONPATH=src python examples/kv_cache.py
 """
-import jax
 import numpy as np
 
 from repro.cache import KVCache, WORKLOADS, YCSBWorkload
-from repro.core.table import DistributedHashTable
+from repro.core.table import DistributedHashTable, table_mesh
 
 
 def main() -> None:
-    d = len(jax.devices())
-    mesh = jax.make_mesh((d,), ("d",))
+    mesh = table_mesh()
     table = DistributedHashTable(
         mesh, ("d",), hash_range=1 << 12, max_deltas=4, tombstone_capacity=512
     )

@@ -12,13 +12,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.table import DistributedHashTable, retrieval_to_lists
+from repro.core.table import DistributedHashTable, retrieval_to_lists, table_mesh
 
 
 def main() -> None:
     rng = np.random.default_rng(0)
-    d = len(jax.devices())
-    mesh = jax.make_mesh((d,), ("d",))
+    mesh = table_mesh()
     n = 1 << 12
 
     table = DistributedHashTable(mesh, ("d",), hash_range=n)

@@ -21,10 +21,9 @@ then shows the three read sides:
 """
 import tempfile
 
-import jax
 import numpy as np
 
-from repro.core.table import DistributedHashTable
+from repro.core.table import DistributedHashTable, table_mesh
 from repro.obs import PHASES, render_prometheus
 from repro.serve_table import (
     AsyncFrontend,
@@ -36,8 +35,7 @@ from repro.serve_table import (
 
 def main() -> None:
     rng = np.random.default_rng(0)
-    d = len(jax.devices())
-    mesh = jax.make_mesh((d,), ("d",))
+    mesh = table_mesh()
     n = 1 << 12
 
     table = DistributedHashTable(
@@ -61,7 +59,7 @@ def main() -> None:
             f"  {p.kind:5s} bucket={p.bucket:<3d} depth={p.depth}  "
             f"all_to_alls={p.all_to_alls}  "
             f"collective_bytes={p.total_collective_bytes}  "
-            f"flop/byte={p.flop_per_byte:.2f}"
+            f"flop/byte={p.flop_per_byte}"
         )
 
     # ---- a traced request stream -------------------------------------------

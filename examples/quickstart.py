@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import hashgraph
-from repro.core.table import DistributedHashTable
+from repro.core.table import DistributedHashTable, table_mesh
 
 
 def main() -> None:
@@ -26,7 +26,7 @@ def main() -> None:
 
     # ---- multi-device (paper Alg. 2: bin, split, all-to-all, build) --------
     d = len(jax.devices())
-    mesh = jax.make_mesh((d,), ("d",))
+    mesh = table_mesh()
     table = DistributedHashTable(mesh, ("d",), hash_range=n)
     state = table.build(keys)
     dcounts = table.query(state, queries)

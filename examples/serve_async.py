@@ -13,10 +13,9 @@ grid first, so no live request ever traces or compiles.
 """
 import time
 
-import jax
 import numpy as np
 
-from repro.core.table import DistributedHashTable
+from repro.core.table import DistributedHashTable, table_mesh
 from repro.serve_table import (
     AsyncFrontend,
     CompactionPolicy,
@@ -27,8 +26,7 @@ from repro.serve_table import (
 
 def main() -> None:
     rng = np.random.default_rng(0)
-    d = len(jax.devices())
-    mesh = jax.make_mesh((d,), ("d",))
+    mesh = table_mesh()
     n = 1 << 12
 
     table = DistributedHashTable(

@@ -9,17 +9,15 @@ fold that never touches the read path.
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         PYTHONPATH=src python examples/serve_table.py
 """
-import jax
 import numpy as np
 
-from repro.core.table import DistributedHashTable
+from repro.core.table import DistributedHashTable, table_mesh
 from repro.serve_table import CompactionPolicy, TableServer
 
 
 def main() -> None:
     rng = np.random.default_rng(0)
-    d = len(jax.devices())
-    mesh = jax.make_mesh((d,), ("d",))
+    mesh = table_mesh()
     n = 1 << 12
 
     table = DistributedHashTable(mesh, ("d",), hash_range=n, max_deltas=6)

@@ -86,8 +86,12 @@ def pack_by_destination(
     """
     n = dest.shape[0]
     dest = dest.astype(jnp.int32)
-    perm = jnp.argsort(dest, stable=True)
-    sdest = dest[perm]
+    # Stable argsort by destination: the row index as the second sort key
+    # gives a stable sort's order, and an unstable sort compiles in about
+    # half the time of a stable one on TPU.
+    sdest, perm = jax.lax.sort(
+        (dest, jnp.arange(n, dtype=jnp.int32)), num_keys=2, is_stable=False
+    )
     # First row of each destination partition in the sorted order.
     starts = jnp.searchsorted(sdest, jnp.arange(num_dest, dtype=jnp.int32), side="left")
     rank_in_part = jnp.arange(n, dtype=jnp.int32) - starts[sdest]

@@ -71,22 +71,32 @@ def _from_cols(cols: Sequence, ndim: int) -> jax.Array:
     return jnp.stack(cols, axis=-1)
 
 
-def _rows_lt_eq(a: jax.Array, b: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Elementwise row comparison ``(a < b, a == b)``.
+def _lanes_lt_eq(a: tuple, b: tuple) -> tuple[jax.Array, jax.Array]:
+    """Elementwise ``(a < b, a == b)`` for keys given as lane tuples.
 
-    1-D arrays compare directly; (..., L) lane arrays compare as packed
-    big integers (lane L-1 most significant — numeric uint64 order for the
-    2-lane packing).
+    Lanes compare as packed big integers, lane ``L-1`` most significant
+    (numeric uint64 order for the 2-lane packing).  Keys travel as 1-D
+    lanes, never as ``(M, L)`` rows, through the large searches: XLA:TPU
+    tiles an ``(M, L)`` intermediate with small ``L`` as ``(8, 128)`` rows,
+    ``128 / L`` times its size.
     """
-    if a.ndim == 1:
-        return a < b, a == b
-    lt = jnp.zeros(jnp.broadcast_shapes(a.shape[:-1], b.shape[:-1]), bool)
-    eq = jnp.ones_like(lt)
-    for l in reversed(range(a.shape[-1])):
-        al, bl = a[..., l], b[..., l]
+    lt, eq = a[-1] < b[-1], a[-1] == b[-1]
+    for al, bl in zip(reversed(a[:-1]), reversed(b[:-1])):
         lt = lt | (eq & (al < bl))
         eq = eq & (al == bl)
     return lt, eq
+
+
+def take_rows(arr: jax.Array, idx: jax.Array) -> jax.Array:
+    """``arr[idx]`` for ``(N,)`` or ``(N, C)`` arrays, one column at a time.
+
+    A row gather from ``(N, C)`` with small ``C`` yields an intermediate
+    XLA:TPU tiles as ``(8, 128)`` rows (``128 / C`` times its size, past
+    HBM at 2^25 rows on v5e); column gathers keep it 1-D.
+    """
+    if arr.ndim == 1:
+        return arr[idx]
+    return jnp.stack([c[idx] for c in _cols(arr)], axis=-1)
 
 
 def match_epochs(
@@ -126,8 +136,12 @@ def sort_tombstones(
         return ts_keys, ts_epochs
     key_cols = _cols(ts_keys)
     sort_ops = tuple(reversed(key_cols))  # most-significant lane first
+    # Every operand is a sort key, so equal entries are identical and a
+    # stable sort (twice the TPU compile time) would change nothing.
     out = jax.lax.sort(
-        (*sort_ops, ts_epochs.astype(jnp.int32)), num_keys=len(sort_ops) + 1
+        (*sort_ops, ts_epochs.astype(jnp.int32)),
+        num_keys=len(sort_ops) + 1,
+        is_stable=False,
     )
     sorted_keys = _from_cols(tuple(reversed(out[: len(key_cols)])), ts_keys.ndim)
     return sorted_keys, out[-1]
@@ -151,9 +165,11 @@ def match_epochs_sorted(
     m = keys.shape[0]
     lo = jnp.zeros((m,), jnp.int32)
     hi = jnp.full((m,), t, jnp.int32)
-    right = _segment_searchsorted(ts_keys, lo, hi, keys, side="right")
+    ts_lanes, key_lanes = _cols(ts_keys), _cols(keys)
+    right = _segment_searchsorted(ts_lanes, lo, hi, key_lanes, side="right")
     idx = jnp.clip(right - 1, 0, t - 1)
-    hit = (right > 0) & rows_equal(ts_keys[idx], keys)
+    _, eq = _lanes_lt_eq(tuple(c[idx] for c in ts_lanes), key_lanes)
+    hit = (right > 0) & eq
     return jnp.where(hit, ts_epochs[idx].astype(jnp.int32), jnp.int32(-1))
 
 
@@ -245,27 +261,37 @@ def build_from_buckets(
     fingerprint = bool(fingerprint) and sort_within_bucket
     # Lexicographic sort by (bucket, [fingerprint,] key) with multi-lane keys
     # compared as packed big integers: lane L-1 (most significant) first,
-    # lane 0 last.  Value columns ride along unsorted-by.  With the
-    # fingerprint lane enabled the within-bucket order is (fp, key) — equal
-    # keys share a fingerprint, so per-key runs stay contiguous and the
-    # stable sort keeps their input order, same as the plain (key) order.
+    # lane 0 last.  With the fingerprint lane enabled the within-bucket order
+    # is (fp, key) — equal keys share a fingerprint, so per-key runs stay
+    # contiguous.  A row index is the last sort key: it keeps equal keys in
+    # input order (what a stable sort gives, while the sort itself may be
+    # unstable) and yields the permutation that gathers the payload
+    # afterwards.  Payload columns do not ride through the sort: a TPU
+    # sort's compile time grows with every operand it carries (about 20 s
+    # each at 2^25 rows on v5e, twice that for a stable sort), a gather's
+    # does not.
     key_cols = _cols(keys)
-    val_cols = _cols(values)
     fp_ops: tuple = ()
     if fingerprint:
         fp_ops = (hashing.fingerprint32(keys),)
-    sort_key_ops = (*fp_ops, *reversed(key_cols))
-    num_keys = 1 + len(sort_key_ops) if sort_within_bucket else 1
+    sort_key_ops = (*fp_ops, *reversed(key_cols)) if sort_within_bucket else ()
+    rows = jnp.arange(keys.shape[0], dtype=jnp.int32)
     out = jax.lax.sort(
-        (buckets, *sort_key_ops, *val_cols), num_keys=num_keys, is_stable=True
+        (buckets, *sort_key_ops, rows),
+        num_keys=len(sort_key_ops) + 2,
+        is_stable=False,  # the row index already makes the order total
     )
     sorted_buckets = out[0]
+    perm = out[-1]
     nf = len(fp_ops)
     sorted_fp = out[1] if fingerprint else None
-    sorted_keys = _from_cols(
-        tuple(reversed(out[1 + nf : 1 + nf + len(key_cols)])), keys.ndim
-    )
-    sorted_values = _from_cols(out[1 + nf + len(key_cols) :], values.ndim)
+    if sort_within_bucket:
+        sorted_keys = _from_cols(
+            tuple(reversed(out[1 + nf : 1 + nf + len(key_cols)])), keys.ndim
+        )
+    else:
+        sorted_keys = take_rows(keys, perm)
+    sorted_values = take_rows(values, perm)
     # offsets[v] = first index whose bucket id >= v ;  offsets[V+1] = N.
     offsets = jnp.searchsorted(
         sorted_buckets, jnp.arange(table_size + 2, dtype=jnp.int32), side="left"
@@ -309,10 +335,10 @@ def build(
 
 
 def _segment_searchsorted(
-    sorted_keys: jax.Array,
+    sorted_keys,
     lo: jax.Array,
     hi: jax.Array,
-    q: jax.Array,
+    q,
     side: str,
 ) -> jax.Array:
     """Vectorized binary search of ``q[i]`` within ``sorted_keys[lo[i]:hi[i]]``.
@@ -320,21 +346,33 @@ def _segment_searchsorted(
     Branchless bisection with a fixed iteration count (log2 of array size),
     so it lowers to a small unrolled loop of gathers — no data-dependent
     control flow, TPU-friendly.  Multi-lane keys compare as packed big
-    integers (lane L-1 most significant), gathering every lane at ``mid``.
+    integers (lane L-1 most significant).
+
+    ``sorted_keys`` and ``q`` are arrays, or both tuples of 1-D lanes.  The
+    array form gathers whole key rows at ``mid`` — right for a small query
+    batch against a large table.  A large ``q`` against a small sorted
+    array (tombstone masking of a whole layer) passes lanes: each step
+    then gathers 1-D lanes and no ``(len(q), L)`` intermediate exists (see
+    :func:`_lanes_lt_eq`).
     """
-    n = sorted_keys.shape[0]
+    lanes = isinstance(sorted_keys, tuple)
+    n = (sorted_keys[0] if lanes else sorted_keys).shape[0]
     # A range of length L needs bit_length(L) halvings to reach lo == hi
     # (bit_length(n-1) is one short when a bucket spans the whole array —
     # found by hypothesis on a 2-key table with both keys in one bucket).
     iters = max(1, int(n).bit_length())
     lo = lo.astype(jnp.int32)
     hi = hi.astype(jnp.int32)
+    q_lanes = q if lanes else _cols(q)
 
     def body(_, lohi):
         lo, hi = lohi
-        mid = (lo + hi) >> 1
-        v = sorted_keys[jnp.clip(mid, 0, n - 1)]
-        v_lt, v_eq = _rows_lt_eq(v, q)
+        mid = jnp.clip((lo + hi) >> 1, 0, n - 1)
+        if lanes:
+            v = tuple(c[mid] for c in sorted_keys)
+        else:
+            v = _cols(sorted_keys[mid])
+        v_lt, v_eq = _lanes_lt_eq(v, q_lanes)
         go_right = v_lt if side == "left" else (v_lt | v_eq)
         active = lo < hi
         new_lo = jnp.where(active & go_right, mid + 1, lo)

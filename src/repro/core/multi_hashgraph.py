@@ -516,17 +516,22 @@ class ShardJoin:
 
 
 def _use_kernel_default(use_kernel: Optional[bool]) -> bool:
-    """Resolve the kernel-path flag: auto-on on TPU, jnp fallback elsewhere."""
-    if use_kernel is None:
-        return jax.default_backend() == "tpu"
+    """Resolve the kernel-path flag: the XLA gather unless asked otherwise.
+
+    The Pallas gathers (``kernels.ops.csr_gather*``) do not lower for TPU:
+    compiled for v5e, Mosaic refuses their dynamic gather from a flattened
+    VMEM ref ("Only 2D gather is supported") at every table size, and each
+    would hold the owner's whole values column in VMEM.  So every backend
+    defaults to ``hashgraph.csr_gather``; ``use_kernel=True`` selects the
+    kernels (interpret mode off the chip, where their tests run).
+    """
     return bool(use_kernel)
 
 
 def _csr_gather_any(starts, counts, table, capacity: int, use_kernel: bool):
-    """CSR gather via the Pallas kernel (TPU hot path) or the jnp idiom.
+    """CSR gather via the Pallas kernel or the jnp idiom (the default).
 
-    Same ``(offsets, row_idx, gathered, num_dropped)`` contract either way;
-    the kernel path is the ROADMAP "kernel-path retrieval" item.
+    Same ``(offsets, row_idx, gathered, num_dropped)`` contract either way.
     """
     if use_kernel:
         from repro.kernels import ops as kernel_ops
@@ -739,10 +744,10 @@ def _retrieve_parts(
     coherent single-layer stack.
 
     ``use_kernel`` selects the Pallas ``csr_gather`` kernel for both gather
-    stages (None = auto: on for TPU, jnp elsewhere).  Both paths produce
-    identical outputs (same per-query epoch-order value runs), including the
-    ``per_layer`` count breakdown (fused: shipped in the same all-to-all;
-    legacy: stacked from the per-layer return trips).
+    stages (None/False: the XLA gather, see :func:`_use_kernel_default`).
+    Both paths produce identical outputs (same per-query epoch-order value
+    runs), including the ``per_layer`` count breakdown (fused: shipped in
+    the same all-to-all; legacy: stacked from the per-layer return trips).
     """
     layers = tuple(layers)
     nlayers = len(layers)

@@ -41,10 +41,23 @@ def pack_u64(keys) -> jax.Array:
 
     Lane 0 is the low 32 bits, lane 1 the high 32 bits.
     """
+    return jnp.asarray(_pack_u64_host(keys))
+
+
+def _pack_u64_host(keys) -> np.ndarray:
     a = np.asarray(keys, dtype=np.uint64)
     lo = (a & np.uint64(0xFFFFFFFF)).astype(np.uint32)
     hi = (a >> np.uint64(32)).astype(np.uint32)
-    return jnp.asarray(np.stack([lo, hi], axis=-1))
+    return np.stack([lo, hi], axis=-1)
+
+
+def _place(x, sharding):
+    """A device array of ``x``: on ``sharding`` when given, else the default
+    device.  Host arrays are transferred once, straight to their shards;
+    traced values are left to the enclosing program's shardings."""
+    if sharding is None or isinstance(x, jax.core.Tracer):
+        return jnp.asarray(x)
+    return jax.device_put(x, sharding)
 
 
 def unpack_u64(packed) -> np.ndarray:
@@ -79,46 +92,75 @@ class TableSchema:
     def key_lanes(self) -> int:
         return 2 if self.key_dtype == "uint64" else 1
 
-    # -- device-array canonicalization --------------------------------------
-    def pack_keys(self, keys) -> jax.Array:
-        """Canonical device layout: ``(N,)`` uint32 or ``(N, 2)`` uint32.
+    # -- canonicalization ----------------------------------------------------
+    def host_keys(self, keys) -> np.ndarray:
+        """Canonical packed keys on the host: ``(N,)`` or ``(N, 2)`` uint32.
 
-        Accepts host numpy arrays (uint64 arrays are split into lanes) or
-        already-packed device arrays; validates the lane count.
+        Accepts numpy arrays or python ints (64-bit values are split into
+        lanes for the uint64 schema) and device arrays (fetched).
         """
-        if isinstance(keys, np.ndarray) and keys.dtype in (np.uint64, np.int64):
+        keys = np.asarray(keys)
+        if keys.dtype in (np.uint64, np.int64):
             if self.key_lanes == 2:
-                if keys.dtype == np.int64:
-                    if (keys < 0).any():
-                        raise ValueError("uint64 schema got negative int64 keys")
-                    keys = keys.astype(np.uint64)
-                keys = pack_u64(keys)
-            else:
+                if keys.dtype == np.int64 and (keys < 0).any():
+                    raise ValueError("uint64 schema got negative int64 keys")
+                keys = _pack_u64_host(keys)
+            elif (keys < 0).any() or (keys > 0xFFFFFFFF).any():
                 # 1-lane schema: reject wide values instead of wrapping mod 2^32.
-                if (keys < 0).any() or (keys > 0xFFFFFFFF).any():
-                    raise ValueError(
-                        "uint32 schema got 64-bit key values out of range; "
-                        "use TableSchema('uint64')"
-                    )
-                keys = keys.astype(np.uint32)
-        keys = jnp.asarray(keys)
-        keys = keys.astype(jnp.uint32)
-        if self.key_lanes == 1:
-            if keys.ndim != 1:
                 raise ValueError(
-                    f"uint32 schema expects (N,) keys, got shape {keys.shape}"
+                    "uint32 schema got 64-bit key values out of range; "
+                    "use TableSchema('uint64')"
                 )
-        else:
-            if keys.ndim != 2 or keys.shape[-1] != 2:
-                raise ValueError(
-                    f"uint64 schema expects (N, 2) packed uint32 keys "
-                    f"(see schema.pack_u64), got shape {keys.shape}"
-                )
+        keys = keys.astype(np.uint32)
+        self._check_key_shape(keys.shape)
         return keys
 
-    def pack_values(self, values) -> jax.Array:
-        """Canonical payload layout: ``(N,)`` or ``(N, C)`` int32."""
-        values = jnp.asarray(values).astype(jnp.int32)
+    def pack_keys(self, keys, sharding=None) -> jax.Array:
+        """Canonical device layout: ``(N,)`` uint32 or ``(N, 2)`` uint32.
+
+        Accepts host arrays (canonicalized by :meth:`host_keys`) or
+        already-packed device arrays; validates the lane count.  With
+        ``sharding`` the result is placed on it (host rows go straight to
+        their shards, never through one device).
+        """
+        if isinstance(keys, jax.Array):
+            keys = keys.astype(jnp.uint32)
+            self._check_key_shape(keys.shape)
+        else:
+            keys = self.host_keys(keys)
+        return _place(keys, sharding)
+
+    def _check_key_shape(self, shape) -> None:
+        if self.key_lanes == 1:
+            if len(shape) != 1:
+                raise ValueError(f"uint32 schema expects (N,) keys, got shape {shape}")
+        elif len(shape) != 2 or shape[-1] != 2:
+            raise ValueError(
+                f"uint64 schema expects (N, 2) packed uint32 keys "
+                f"(see schema.pack_u64), got shape {shape}"
+            )
+
+    def host_values(self, values) -> np.ndarray:
+        """Canonical payload on the host: ``(N,)`` or ``(N, C)`` int32."""
+        return self._check_values(np.asarray(values).astype(np.int32))
+
+    def pack_values(self, values, sharding=None) -> jax.Array:
+        """Canonical payload layout: ``(N,)`` or ``(N, C)`` int32, placed on
+        ``sharding`` when given (see :meth:`pack_keys`)."""
+        if isinstance(values, jax.Array):
+            values = self._check_values(values.astype(jnp.int32))
+        else:
+            values = self.host_values(values)
+        return _place(values, sharding)
+
+    def default_values(self, n: int) -> np.ndarray:
+        """Row-id payload for ``n`` rows, repeated across every column."""
+        values = np.arange(n, dtype=np.int32)
+        if self.value_cols > 1:
+            values = np.stack([values] * self.value_cols, axis=1)
+        return values
+
+    def _check_values(self, values):
         if self.value_cols == 1:
             if values.ndim == 2 and values.shape[-1] == 1:
                 values = values[:, 0]
@@ -126,10 +168,9 @@ class TableSchema:
                 raise ValueError(
                     f"1-column schema expects (N,) values, got {values.shape}"
                 )
-        else:
-            if values.ndim != 2 or values.shape[-1] != self.value_cols:
-                raise ValueError(
-                    f"schema expects (N, {self.value_cols}) values, "
-                    f"got shape {values.shape}"
-                )
+        elif values.ndim != 2 or values.shape[-1] != self.value_cols:
+            raise ValueError(
+                f"schema expects (N, {self.value_cols}) values, "
+                f"got shape {values.shape}"
+            )
         return values

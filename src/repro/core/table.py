@@ -34,10 +34,10 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.utils.compat import shard_map
-
 import numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+from repro.utils.compat import shard_map
 
 from repro.core import hashing, multi_hashgraph, partition, plans
 from repro.core.hashgraph import (
@@ -55,6 +55,19 @@ from repro.core.plans import JoinPlan, QueryPlan, RetrievePlan
 from repro.core.schema import TableSchema
 from repro.core.state import TableState, as_state, empty_tombstones
 from repro.utils import cdiv as _cdiv
+
+
+def table_mesh(devices=None) -> Mesh:
+    """The 1-D device mesh a :class:`DistributedHashTable` shards over.
+
+    ``devices`` defaults to every device of the process.  The axis is
+    ``Auto``: eager mutations (tombstone pushes, write padding) combine
+    arrays already placed on the mesh with freshly made ones, which an
+    ``Explicit`` axis (``jax.make_mesh``'s default) refuses outside a mesh
+    context.
+    """
+    devices = jax.devices() if devices is None else list(devices)
+    return Mesh(np.asarray(devices), ("d",), axis_types=(AxisType.Auto,))
 
 
 def _dhg_out_specs(
@@ -94,10 +107,11 @@ class DistributedHashTable:
 
     ``schema`` selects key width and payload columns (default: the paper's
     uint32 keys + one int32 column).  ``use_kernel`` routes the retrieval
-    gather through the Pallas ``csr_gather`` kernel (None = auto: on for
-    TPU, jnp path elsewhere).  ``max_deltas`` bounds the insert delta ring
-    and ``tombstone_capacity`` the delete buffer of the versioned state
-    (see :class:`~repro.core.state.TableState`).
+    gather through the Pallas ``csr_gather`` kernel (None: the XLA gather,
+    which is what runs on TPU — Mosaic does not lower the kernels yet).
+    ``max_deltas`` bounds the insert delta ring and ``tombstone_capacity``
+    the delete buffer of the versioned state (see
+    :class:`~repro.core.state.TableState`).
 
     ``coherent_deltas`` (default True) builds every insert delta on the
     base's *frozen* ``hash_splits`` — the partition-coherence invariant
@@ -231,7 +245,8 @@ class DistributedHashTable:
            :class:`TableState` supporting insert/delete/compact.  ``build``
            returns the bare ``DistributedHashGraph`` for older call sites.
         """
-        keys = self.schema.pack_keys(keys)
+        sharding = self.key_sharding()
+        keys = self.schema.pack_keys(keys, sharding)
         if values is None:
             if self.schema.value_cols != 1:
                 raise ValueError(
@@ -240,7 +255,7 @@ class DistributedHashTable:
                 )
             return self._build_jit(keys, hash_range=self.hash_range)
         return self._build_values_jit(
-            keys, self.schema.pack_values(values), hash_range=self.hash_range
+            keys, self.schema.pack_values(values, sharding), hash_range=self.hash_range
         )
 
     def init(self, keys, values=None) -> TableState:
@@ -528,16 +543,16 @@ class DistributedHashTable:
                 f"delta ring full ({self.max_deltas} deltas); call compact() "
                 "to fold deltas into the base before inserting more"
             )
-        keys = self.schema.pack_keys(keys)
+        sharding = self.key_sharding()
+        keys = self.schema.pack_keys(keys, sharding)
         if values is None:
             if self.schema.value_cols != 1:
                 raise ValueError(
                     f"schema has {self.schema.value_cols} value columns; "
                     "pass explicit values (the row-id default is 1-column)"
                 )
-            values = jnp.arange(keys.shape[0], dtype=jnp.int32)
-        else:
-            values = self.schema.pack_values(values)
+            values = np.arange(keys.shape[0], dtype=np.int32)
+        values = self.schema.pack_values(values, sharding)
         coherent_build = self.coherent_deltas
         tracing = any(
             isinstance(x, jax.core.Tracer)
@@ -852,8 +867,17 @@ class DistributedHashTable:
             keys_cat = deal(keys_cat)
             vals_cat = deal(vals_cat)
             # Live rows first: exchange-capacity drops hit sentinels before
-            # any real key (pack order within a destination is stable).
-            order = jnp.argsort(is_empty_key(keys_cat).astype(jnp.int32), stable=True)
+            # any real key (pack order within a destination is stable; the
+            # row index is the second sort key, as in
+            # exchange.pack_by_destination).
+            _, order = jax.lax.sort(
+                (
+                    is_empty_key(keys_cat).astype(jnp.int32),
+                    jnp.arange(keys_cat.shape[0], dtype=jnp.int32),
+                ),
+                num_keys=2,
+                is_stable=False,
+            )
             keys_cat = keys_cat[order]
             vals_cat = vals_cat[order]
             trunc_live = jnp.int32(0)

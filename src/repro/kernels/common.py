@@ -51,11 +51,13 @@ def resolve_block_rows(
 
 
 def use_interpret_mode() -> bool:
-    """Pallas TPU kernels run in interpret mode on non-TPU backends.
+    """Pallas TPU kernels run in interpret mode on non-TPU backends only.
 
-    This container is CPU-only: TPU is the *target*, interpret mode is the
-    validation vehicle (assignment contract).  On a real TPU this returns
-    False and the kernels lower natively.
+    The tests run on the CPU, where interpret mode checks a kernel's
+    results; on a TPU this returns False and the kernels lower through
+    Mosaic.  Interpret mode never runs on the chip, and it cannot show
+    what Mosaic refuses: ``tests/test_compile_v5e.py`` compiles for a
+    described v5e for that.
     """
     return jax.default_backend() != "tpu"
 

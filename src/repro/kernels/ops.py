@@ -1,8 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Each wrapper handles padding/reshaping to the TPU ``(rows, 128)`` lane
-layout and chooses interpret mode automatically off-TPU (this container is
-CPU-only; TPU is the lowering target, interpret mode the validator).
+layout and runs the kernel in interpret mode on non-TPU backends (the CPU
+test runs), natively on a TPU.  The ``csr_gather*`` kernels do not lower
+for TPU yet, so the table's read path uses the XLA gather by default
+(see ``multi_hashgraph._use_kernel_default``).
 
 ``block_rows`` left ``None`` resolves through
 :func:`repro.kernels.common.resolve_block_rows` — autotuned winner if the

@@ -26,9 +26,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-# Renamed TPUCompilerParams -> CompilerParams across pallas versions.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(pre_ref, r_ref, c0_ref, n0_ref, h0_ref, m0_ref,
             hs_ref, cf_ref, nf_ref, hf_ref, mf_ref,
@@ -151,7 +148,7 @@ def slstm_sequence(
             pltpu.VMEM((1, hd), jnp.float32),
             pltpu.VMEM((1, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

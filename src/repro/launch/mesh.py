@@ -25,15 +25,23 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_smoke_mesh(devices: Optional[int] = None) -> jax.sharding.Mesh:
     """Small mesh over however many (fake) devices the process has."""
     n = devices or len(jax.devices())
     if n >= 4:
-        return jax.make_mesh((n // 2, 2), ("data", "model"))
-    return jax.make_mesh((n,), ("data",))
+        return auto_mesh((n // 2, 2), ("data", "model"))
+    return auto_mesh((n,), ("data",))
+
+
+def auto_mesh(shape, axes) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with ``Auto`` axes (its default is ``Explicit``),
+    which the sharding rules and steps here are written for."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def production_parallel(

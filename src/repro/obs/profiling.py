@@ -9,10 +9,10 @@ Two complementary sources, combined per compiled executor:
   exact program structure, independent of the backend: it is how the CI
   gate *independently re-confirms* the fused routing budget (exactly two
   all-to-alls per query/retrieve at every delta depth).
-* **XLA cost analysis** (via the :func:`~repro.utils.compat.
-  compiled_cost_analysis` shim): FLOPs and bytes-accessed estimates from
-  the compiled executable, giving a FLOP/byte arithmetic-intensity figure
-  per executor.
+* **XLA cost analysis** (via :func:`~repro.utils.compat.
+  compiled_cost_analysis`): FLOPs and bytes-accessed estimates from the
+  compiled executable, giving a FLOP/byte arithmetic-intensity figure per
+  executor.  A figure XLA does not report stays ``None`` (not measured).
 
 ``warm_server`` runs :func:`profile_executor` once per distinct program
 structure in the AOT grid and stores the resulting
@@ -25,7 +25,7 @@ import dataclasses
 from typing import Optional
 
 import jax
-import jax.core as jcore
+from jax.extend import core as jcore
 
 from repro.utils.compat import compiled_cost_analysis
 
@@ -86,7 +86,8 @@ class ExecutorCost:
     Collective counts/bytes come from the jaxpr walk (bytes are per-device
     output payload of each collective, summed over occurrences); ``flops``
     and ``bytes_accessed`` come from XLA's cost analysis of the compiled
-    executable (0.0 when the backend doesn't report them).
+    executable (``None`` — not measured — without an executable, or when
+    XLA reports no such figure).
     """
 
     kind: str  # "query" | "retrieve" | ...
@@ -94,8 +95,8 @@ class ExecutorCost:
     depth: int  # delta depth of the state structure
     collective_counts: dict  # primitive name -> occurrence count
     collective_bytes: dict  # primitive name -> summed output bytes
-    flops: float = 0.0
-    bytes_accessed: float = 0.0
+    flops: Optional[float] = None
+    bytes_accessed: Optional[float] = None
 
     @property
     def all_to_alls(self) -> int:
@@ -110,8 +111,10 @@ class ExecutorCost:
         return sum(self.collective_bytes.values())
 
     @property
-    def flop_per_byte(self) -> float:
-        return self.flops / self.bytes_accessed if self.bytes_accessed else 0.0
+    def flop_per_byte(self) -> Optional[float]:
+        if self.flops is None or not self.bytes_accessed:
+            return None
+        return self.flops / self.bytes_accessed
 
     def as_dict(self) -> dict:
         return {
@@ -172,15 +175,13 @@ def profile_executor(
     else:
         raise ValueError(f"unknown executor kind {kind!r}")
     counts, bytes_ = collective_profile(fn, state, queries)
-    flops = 0.0
-    bytes_accessed = 0.0
+    flops = bytes_accessed = None
     if compiled is not None:
-        try:
-            cost = compiled_cost_analysis(compiled)
-        except Exception:  # backend without cost analysis
-            cost = {}
-        flops = float(cost.get("flops", 0.0) or 0.0)
-        bytes_accessed = float(cost.get("bytes accessed", 0.0) or 0.0)
+        cost = compiled_cost_analysis(compiled)
+        if "flops" in cost:
+            flops = float(cost["flops"])
+        if "bytes accessed" in cost:
+            bytes_accessed = float(cost["bytes accessed"])
     return ExecutorCost(
         kind=kind,
         bucket=int(queries.shape[0]),

@@ -284,8 +284,10 @@ def warm_server(
             protos.append((f, d, proto(base, d)))
         if f < fold_horizon:
             # The next fold step's base: fold fold_k sentinel deltas in.
-            # (Also warms the incremental fold executor as a side effect.)
-            base = maintenance.fold_oldest(proto(base, fold_k), fold_k).base
+            # The stack is trigger-deep, as the one the policy folds: the
+            # fold executor is traced per stack depth, so this warms the
+            # very program the writer calls.
+            base = maintenance.fold_oldest(proto(base, trigger), fold_k).base
 
     # -- lower sequentially (tracing), compile on a pool (XLA, GIL-free) ------
     grid = ExecutorGrid()

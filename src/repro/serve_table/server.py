@@ -41,6 +41,7 @@ import time
 from collections import deque
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -173,6 +174,15 @@ class TableServer:
         )
 
     # -- write path (admission) ----------------------------------------------
+    def _host_rows(self, keys, values):
+        """One mutation batch staged on the host: canonical packed keys and
+        payload (row ids when ``values`` is None)."""
+        schema = self.table.schema
+        keys = schema.host_keys(keys)
+        if values is None:
+            values = schema.default_values(keys.shape[0])
+        return keys, schema.host_values(values)
+
     def _pad_insert(self, keys, values, bucket: Optional[int] = None):
         """Device-align one mutation batch: EMPTY-pad keys, -1-pad values.
 
@@ -181,28 +191,19 @@ class TableServer:
         every read — the same padding idiom as the exchange.  With
         ``bucket`` the batch is padded all the way to that fixed size, so
         every delta it builds shares one geometry (the AOT grid contract).
+        Padding happens on the host; the padded rows then go straight to
+        the table's key sharding.
         """
-        schema = self.table.schema
-        keys = schema.pack_keys(keys)
+        keys, values = self._host_rows(keys, values)
         n = keys.shape[0]
-        if values is None:
-            values = np.arange(n, dtype=np.int32)
-            if schema.value_cols > 1:
-                values = np.stack(
-                    [values] * schema.value_cols, axis=1
-                )
-        values = schema.pack_values(values)
         pad = (-n) % self.table.num_devices if bucket is None else bucket - n
         if pad:
-            kshape = (pad,) + tuple(keys.shape[1:])
-            vshape = (pad,) + tuple(values.shape[1:])
-            keys = jnp.concatenate(
-                [keys, jnp.full(kshape, EMPTY_KEY, jnp.uint32)]
-            )
-            values = jnp.concatenate(
-                [values, jnp.full(vshape, -1, jnp.int32)]
-            )
-        return keys, values
+            kshape = (pad,) + keys.shape[1:]
+            vshape = (pad,) + values.shape[1:]
+            keys = np.concatenate([keys, np.full(kshape, EMPTY_KEY, np.uint32)])
+            values = np.concatenate([values, np.full(vshape, -1, np.int32)])
+        sharding = self.table.key_sharding()
+        return jax.device_put(keys, sharding), jax.device_put(values, sharding)
 
     def submit_insert(self, keys, values=None) -> None:
         """Queue one insert batch (applied by the writer loop).
@@ -213,14 +214,8 @@ class TableServer:
         :meth:`warm` enumerate (and AOT-compile) every state structure the
         writer can reach.
         """
-        schema = self.table.schema
-        keys = schema.pack_keys(keys)
+        keys, values = self._host_rows(keys, values)
         n = keys.shape[0]
-        if values is None:
-            values = np.arange(n, dtype=np.int32)
-            if schema.value_cols > 1:
-                values = np.stack([values] * schema.value_cols, axis=1)
-        values = schema.pack_values(values)
         wb = self.write_bucket
         if wb is None:
             ops = [self._pad_insert(keys, values)]
@@ -243,7 +238,7 @@ class TableServer:
         unusually permissive policy still surfaces in
         ``stats().shadow.tombstone_dropped``.
         """
-        keys = self.table.schema.pack_keys(keys)
+        keys = self.table.schema.host_keys(keys)
         chunk = max(1, self.table.tombstone_capacity // 2)
         with self._lock:
             for i in range(0, max(1, keys.shape[0]), chunk):
@@ -261,27 +256,18 @@ class TableServer:
         the new version at ``now + ttl`` on the server's logical clock
         (:meth:`advance`).
         """
-        schema = self.table.schema
-        keys = schema.pack_keys(keys)
-        n = keys.shape[0]
-        if values is None:
-            values = np.arange(n, dtype=np.int32)
-            if schema.value_cols > 1:
-                values = np.stack([values] * schema.value_cols, axis=1)
-        values = schema.pack_values(values)
+        kn, vn = self._host_rows(keys, values)
         # Keep-last dedup at admission: KV semantics demand one winner per
         # key per batch, and deduping host-side keeps the applied chunks
         # disjoint (cross-chunk duplicates would re-tombstone fresh rows).
-        kn = np.asarray(keys)
-        vn = np.asarray(values)
         rows = kn if kn.ndim == 2 else kn[:, None]
         _, first = np.unique(rows[::-1], axis=0, return_index=True)
         keep = np.sort(rows.shape[0] - 1 - first)
         keep = keep[~np.all(rows[keep] == np.uint32(EMPTY_KEY), axis=1)]
         if keep.shape[0] == 0:
             return
-        keys = jnp.asarray(kn[keep])
-        values = jnp.asarray(vn[keep])
+        keys = kn[keep]
+        values = vn[keep]
         chunk = self.write_bucket or max(1, keys.shape[0])
         chunk = min(chunk, max(1, self.table.tombstone_capacity // 2))
         with self._lock:

@@ -1,67 +1,35 @@
-"""Version shims for jax APIs that moved between releases.
+"""Thin wrappers over jax APIs whose spelling the call sites keep stable.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to ``jax.shard_map``
-(and its replication-check kwarg was renamed ``check_rep`` → ``check_vma``
-along the way).  Every call site in this repo imports the shim and uses the
-modern keyword spelling; the shim translates for older jax.
+Written for the one installed jax (0.9): ``shard_map`` is ``jax.shard_map``
+(replication check ``check_vma``), ``axis_size`` is ``jax.lax.axis_size``,
+``AbstractMesh`` takes ``(axis_sizes, axis_names)`` and
+``Compiled.cost_analysis()`` returns a dict.
 """
 from __future__ import annotations
 
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # jax 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
+from jax import shard_map as _shard_map
+from jax.lax import axis_size
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True, axis_names=None, **kwargs):
-    """``jax.shard_map`` with a stable keyword interface across jax versions.
-
-    ``axis_names`` (the manual axes, new-style) maps onto the old API's
-    complementary ``auto`` set.
-    """
-    kwargs[_CHECK_KW] = check_vma
+    """``jax.shard_map`` with ``axis_names`` (the manual axes) optional."""
     if axis_names is not None:
-        if _CHECK_KW == "check_vma":
-            kwargs["axis_names"] = axis_names
-        else:
-            kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-try:  # jax >= 0.4.38
-    from jax.lax import axis_size
-except ImportError:  # older jax: the axis frame holds the static size
-    import jax.core as _core
-
-    def axis_size(name):
-        """Static size of a shard_map mesh axis (python int)."""
-        return _core.axis_frame(name)
+        kwargs["axis_names"] = axis_names
+    return _shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma, **kwargs
+    )
 
 
 def abstract_mesh(axis_sizes, axis_names):
-    """``jax.sharding.AbstractMesh`` across the constructor-signature change.
-
-    Newer jax takes ``(axis_sizes, axis_names)``; older jax takes a single
-    ``((name, size), ...)`` shape tuple.
-    """
+    """``jax.sharding.AbstractMesh`` from parallel size/name sequences."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def compiled_cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a dict (older jaxlib returns a list)."""
-    c = compiled.cost_analysis()
-    if isinstance(c, (list, tuple)):
-        c = c[0] if c else {}
-    return dict(c or {})
+    """``Compiled.cost_analysis()`` as a dict (empty when XLA reports none)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 __all__ = ["shard_map", "axis_size", "abstract_mesh", "compiled_cost_analysis"]

@@ -17,6 +17,8 @@ import jax  # noqa: E402  (import after the flag so it takes effect)
 
 import pytest
 
+from repro.core.table import table_mesh
+
 # The suite compiles hundreds of XLA programs in one process; on some
 # CPU-only hosts the accumulated compiler/runtime state eventually
 # crashes the process (segfault in backend_compile ~240 tests in,
@@ -25,10 +27,9 @@ import pytest
 # JIT memory) a finished module pinned, which keeps the single-process
 # tier-1 run inside what the toolchain tolerates.  No test observes the
 # difference: jit cache-size assertions are all intra-test, and the
-# next module simply recompiles what it needs.  (A per-run persistent
-# compilation cache would also dampen this, but deserialized CPU
-# executables abort on the host-callback programs the trainer and
-# checkpoint tests compile — jax 0.4.37 — so it stays off.)
+# next module simply recompiles what it needs.  Tests never turn on the
+# persistent compilation cache (``repro.utils.compile_cache`` is for the
+# chip entry points only).
 
 
 @pytest.fixture(autouse=True, scope="module")
@@ -42,10 +43,10 @@ def mesh8():
     """An 8-way 1-D mesh over the forced host devices."""
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 (fake) devices; XLA_FLAGS was overridden")
-    return jax.make_mesh((8,), ("d",))
+    return table_mesh(jax.devices()[:8])
 
 
 @pytest.fixture(scope="session")
 def mesh1():
     """Single-device mesh (degenerate distributed case)."""
-    return jax.make_mesh((1,), ("d",))
+    return table_mesh(jax.devices()[:1])

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.table import DistributedHashTable
+from repro.launch.mesh import auto_mesh
 
 
 def check(name, cond):
@@ -35,7 +36,7 @@ def np_counts(build_keys, query_keys):
 def main():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 fake devices, got {len(devs)}"
-    mesh = jax.make_mesh((2, 4), ("x", "y"))
+    mesh = auto_mesh((2, 4), ("x", "y"))
     rng = np.random.default_rng(0)
 
     # ---- 1. random keys, exact multiset counts ----------------------------
@@ -96,7 +97,7 @@ def main():
     check("all_keys_distributed", sizes.sum() == n)
 
     # ---- 7. single-axis mesh (flat 8) ---------------------------------------
-    mesh1 = jax.make_mesh((8,), ("d",))
+    mesh1 = auto_mesh((8,), ("d",))
     t1 = DistributedHashTable(mesh1, ("d",), hash_range=hr)
     s1 = t1.build(jnp.asarray(keys))
     c1 = np.asarray(t1.query(s1, jnp.asarray(queries)))

@@ -17,6 +17,7 @@ from repro.core.table import DistributedHashTable
 from repro.data import dedup_mask, dedup_mask_distributed
 from repro.distributed.parallel import ParallelConfig
 from repro.distributed import sharding as shd
+from repro.launch.mesh import auto_mesh
 from repro.models import moe as moe_mod
 from repro.models.api import build_model
 
@@ -34,7 +35,7 @@ def moe_ep_matches_dense():
         get_smoke_config("mixtral_8x22b"), dtype="float32", num_experts=4,
         moe_capacity_factor=4.0,  # generous: no drops → exact equality
     )
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = auto_mesh((8,), ("data",))
     parallel = ParallelConfig(
         mesh=mesh, dp_axes=("data",), tp_axis=None, moe_impl="ep"
     )
@@ -60,7 +61,7 @@ def distributed_dedup_matches_local():
     toks = np.concatenate([base, base[:16]])  # 16 duplicate rows
     local = np.asarray(dedup_mask(jnp.asarray(toks)))
 
-    mesh = jax.make_mesh((8,), ("d",))
+    mesh = auto_mesh((8,), ("d",))
     table = DistributedHashTable(mesh, ("d",), hash_range=256)
     dist = np.asarray(dedup_mask_distributed(table, jnp.asarray(toks)))
     check("distributed_dedup_matches_local", (local == dist).all())
@@ -73,7 +74,7 @@ def seq_sharded_decode_matches_single():
     cfg = dataclasses.replace(
         get_smoke_config("granite_20b"), dtype="float32", num_layers=2
     )
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     parallel = ParallelConfig(mesh=mesh, dp_axes=("data",), tp_axis="model")
     bundle = build_model(cfg, parallel)
     params = bundle.init(jax.random.key(3))

@@ -20,6 +20,7 @@ from repro.utils.compat import shard_map
 from repro.configs.base import get_smoke_config
 from repro.data import ShardedLoader, SyntheticCorpus
 from repro.distributed.parallel import ParallelConfig, single_device_parallel
+from repro.launch.mesh import auto_mesh
 from repro.models.api import build_model
 from repro.optim.compress import compressed_psum_int8
 from repro.train import Trainer, TrainerConfig, TrainStepConfig
@@ -36,7 +37,7 @@ def check(name, cond):
 
 
 def gspmd_sharded_training():
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     parallel = ParallelConfig(
         mesh=mesh, dp_axes=("data",), tp_axis="model", microbatches=2
     )
@@ -69,7 +70,7 @@ def gspmd_sharded_training():
 
 
 def compressed_psum_close_to_exact():
-    mesh = jax.make_mesh((8,), ("d",))
+    mesh = auto_mesh((8,), ("d",))
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((8, 1000)), jnp.float32)
 
@@ -90,7 +91,7 @@ def compressed_psum_close_to_exact():
 
 
 def manual_dp_with_compression():
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = auto_mesh((8,), ("data",))
     parallel = ParallelConfig(
         mesh=mesh, dp_axes=("data",), tp_axis=None, grad_compression=True
     )
@@ -124,7 +125,7 @@ def elastic_restore_across_meshes():
     cfg = dataclasses.replace(get_smoke_config("qwen3_4b"), num_layers=2)
     tcfg = TrainStepConfig()
 
-    mesh_a = jax.make_mesh((8,), ("data",))
+    mesh_a = auto_mesh((8,), ("data",))
     par_a = ParallelConfig(mesh=mesh_a, dp_axes=("data",), tp_axis=None)
     bundle_a = build_model(cfg, par_a)
     pshapes = bundle_a.param_shapes()
@@ -136,7 +137,7 @@ def elastic_restore_across_meshes():
         m = CheckpointManager(d, async_write=False)
         m.save(1, {"params": params_a})
 
-        mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_b = auto_mesh((2, 4), ("data", "model"))
         par_b = ParallelConfig(mesh=mesh_b, dp_axes=("data",), tp_axis="model")
         specs_b = shd.param_pspecs(pshapes, par_b)
         sh_b = shd.to_named(mesh_b, specs_b)
@@ -167,7 +168,7 @@ def pipeline_parallel_matches_single_device():
     params = bundle_ref.init(jax.random.key(9))
     loss_ref, _ = bundle_ref.loss(params, batch)
 
-    mesh = jax.make_mesh((2,), ("stage",))
+    mesh = auto_mesh((2,), ("stage",))
     par = ParallelConfig(mesh=mesh, dp_axes=(), tp_axis=None)
     bundle_pp = build_model(cfg, par)
     from repro.optim import adamw_init

@@ -40,7 +40,7 @@ from repro.core import hashing, plans
 from repro.core.maintenance import fold_oldest
 from repro.core.schema import TableSchema, pack_u64
 from repro.core.table import DistributedHashTable, retrieval_to_lists
-from test_fused_routing import count_primitive
+from repro.obs.profiling import count_primitive
 from test_table_state import _value_rows, _values_for
 
 SCHEMAS = [

@@ -17,7 +17,6 @@ per-layer legacy path pays one round per layer.
   uint32/uint64 keys × 1/2 value columns on mesh1 and mesh8.
 """
 import jax
-import jax.core as jcore
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -29,39 +28,13 @@ from repro.core.table import (
     join_to_pairs,
     retrieval_to_lists,
 )
+from repro.obs.profiling import count_primitive
 from test_table_state import Oracle, _keys_for, _value_rows, _values_for
 
 SCHEMAS = [
     pytest.param(TableSchema("uint32", 1), id="u32x1"),
     pytest.param(TableSchema("uint64", 2), id="u64x2"),
 ]
-
-
-# ---------------------------------------------------------------------------
-# jaxpr collective counting
-# ---------------------------------------------------------------------------
-
-
-def _iter_jaxprs(v):
-    if isinstance(v, jcore.ClosedJaxpr):
-        yield v.jaxpr
-    elif isinstance(v, jcore.Jaxpr):
-        yield v
-    elif isinstance(v, (tuple, list)):
-        for x in v:
-            yield from _iter_jaxprs(x)
-
-
-def count_primitive(jaxpr, name: str) -> int:
-    """Occurrences of primitive ``name`` anywhere in a (nested) jaxpr."""
-    n = 0
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == name:
-            n += 1
-        for v in eqn.params.values():
-            for sub in _iter_jaxprs(v):
-                n += count_primitive(sub, name)
-    return n
 
 
 def _four_layer_state(table, rng):

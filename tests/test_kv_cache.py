@@ -30,7 +30,7 @@ from repro.core import maintenance, plans
 from repro.core.maintenance import CompactionPolicy, fold_oldest
 from repro.core.schema import TableSchema
 from repro.core.table import DistributedHashTable, retrieval_to_lists
-from test_fused_routing import count_primitive
+from repro.obs.profiling import count_primitive
 from test_table_state import _keys_for, _value_rows, _values_for
 
 SCHEMAS = [

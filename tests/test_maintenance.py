@@ -17,7 +17,7 @@ from repro.core import hashing, maintenance
 from repro.core.maintenance import CompactionPolicy, TableStats, fold_oldest
 from repro.core.schema import TableSchema
 from repro.core.table import DistributedHashTable, retrieval_to_lists
-from test_fused_routing import count_primitive
+from repro.obs.profiling import count_primitive
 from test_table_state import Oracle, _keys_for, _value_rows, _values_for
 
 SCHEMAS = [

@@ -35,7 +35,7 @@ from concurrent.futures import Future
 import numpy as np
 import pytest
 
-from repro.core import plans
+from repro.core import maintenance, plans
 from repro.core.table import DistributedHashTable
 from repro.serve_table import (
     AsyncFrontend,
@@ -531,6 +531,7 @@ def test_no_retrace_after_warmup(mesh8):
 
     has_counter = hasattr(plans.exec_query, "_cache_size")
     jit_before = plans.exec_query._cache_size() if has_counter else None
+    fold_before = maintenance.exec_fold._cache_size() if has_counter else None
 
     def q(keys):
         res, _ = server.query_many([np.asarray(keys, dtype=np.uint32)])
@@ -566,6 +567,9 @@ def test_no_retrace_after_warmup(mesh8):
     if has_counter:
         assert plans.exec_query._cache_size() == jit_before, (
             "a live request traced/compiled despite AOT warmup"
+        )
+        assert maintenance.exec_fold._cache_size() == fold_before, (
+            "the policy fold traced/compiled despite AOT warmup"
         )
 
 
