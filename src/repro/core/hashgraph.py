@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import hashing
+from repro.obs.tracing import stage
 
 # Sentinel key marking capacity padding (reserved; valid keys must be < 2^32-1
 # for 1-lane keys, < 2^64-1 for 2-lane packed keys — the sentinel is all-ones
@@ -252,50 +253,52 @@ def build_from_buckets(
     layout never bisects, so the lane would be dead weight); it is dropped
     silently otherwise.
     """
-    keys = keys.astype(jnp.uint32)
-    buckets = buckets.astype(jnp.int32)
-    if values is None:
-        values = jnp.arange(keys.shape[0], dtype=jnp.int32)
-    if fingerprint is None:
-        fingerprint = keys.ndim == 2
-    fingerprint = bool(fingerprint) and sort_within_bucket
-    # Lexicographic sort by (bucket, [fingerprint,] key) with multi-lane keys
-    # compared as packed big integers: lane L-1 (most significant) first,
-    # lane 0 last.  With the fingerprint lane enabled the within-bucket order
-    # is (fp, key) — equal keys share a fingerprint, so per-key runs stay
-    # contiguous.  A row index is the last sort key: it keeps equal keys in
-    # input order (what a stable sort gives, while the sort itself may be
-    # unstable) and yields the permutation that gathers the payload
-    # afterwards.  Payload columns do not ride through the sort: a TPU
-    # sort's compile time grows with every operand it carries (about 20 s
-    # each at 2^25 rows on v5e, twice that for a stable sort), a gather's
-    # does not.
-    key_cols = _cols(keys)
-    fp_ops: tuple = ()
-    if fingerprint:
-        fp_ops = (hashing.fingerprint32(keys),)
-    sort_key_ops = (*fp_ops, *reversed(key_cols)) if sort_within_bucket else ()
-    rows = jnp.arange(keys.shape[0], dtype=jnp.int32)
-    out = jax.lax.sort(
-        (buckets, *sort_key_ops, rows),
-        num_keys=len(sort_key_ops) + 2,
-        is_stable=False,  # the row index already makes the order total
-    )
-    sorted_buckets = out[0]
-    perm = out[-1]
-    nf = len(fp_ops)
-    sorted_fp = out[1] if fingerprint else None
-    if sort_within_bucket:
-        sorted_keys = _from_cols(
-            tuple(reversed(out[1 + nf : 1 + nf + len(key_cols)])), keys.ndim
+    with stage("build.sort"):
+        keys = keys.astype(jnp.uint32)
+        buckets = buckets.astype(jnp.int32)
+        if values is None:
+            values = jnp.arange(keys.shape[0], dtype=jnp.int32)
+        if fingerprint is None:
+            fingerprint = keys.ndim == 2
+        fingerprint = bool(fingerprint) and sort_within_bucket
+        # Lexicographic sort by (bucket, [fingerprint,] key) with multi-lane
+        # keys compared as packed big integers: lane L-1 (most significant)
+        # first, lane 0 last.  With the fingerprint lane enabled the
+        # within-bucket order is (fp, key) — equal keys share a fingerprint,
+        # so per-key runs stay contiguous.  A row index is the last sort key:
+        # it keeps equal keys in input order (what a stable sort gives, while
+        # the sort itself may be unstable) and yields the permutation that
+        # gathers the payload afterwards.  Payload columns do not ride
+        # through the sort: a TPU sort's compile time grows with every
+        # operand it carries (about 20 s each at 2^25 rows on v5e, twice that
+        # for a stable sort), a gather's does not.
+        key_cols = _cols(keys)
+        fp_ops: tuple = ()
+        if fingerprint:
+            fp_ops = (hashing.fingerprint32(keys),)
+        sort_key_ops = (*fp_ops, *reversed(key_cols)) if sort_within_bucket else ()
+        rows = jnp.arange(keys.shape[0], dtype=jnp.int32)
+        out = jax.lax.sort(
+            (buckets, *sort_key_ops, rows),
+            num_keys=len(sort_key_ops) + 2,
+            is_stable=False,  # the row index already makes the order total
         )
-    else:
-        sorted_keys = take_rows(keys, perm)
-    sorted_values = take_rows(values, perm)
-    # offsets[v] = first index whose bucket id >= v ;  offsets[V+1] = N.
-    offsets = jnp.searchsorted(
-        sorted_buckets, jnp.arange(table_size + 2, dtype=jnp.int32), side="left"
-    ).astype(jnp.int32)
+        sorted_buckets = out[0]
+        perm = out[-1]
+        nf = len(fp_ops)
+        sorted_fp = out[1] if fingerprint else None
+        if sort_within_bucket:
+            sorted_keys = _from_cols(
+                tuple(reversed(out[1 + nf : 1 + nf + len(key_cols)])), keys.ndim
+            )
+        else:
+            sorted_keys = take_rows(keys, perm)
+        sorted_values = take_rows(values, perm)
+    with stage("build.offsets"):
+        # offsets[v] = first index whose bucket id >= v ;  offsets[V+1] = N.
+        offsets = jnp.searchsorted(
+            sorted_buckets, jnp.arange(table_size + 2, dtype=jnp.int32), side="left"
+        ).astype(jnp.int32)
     return HashGraph(
         offsets=offsets,
         keys=sorted_keys,

@@ -26,7 +26,6 @@ a fold, only the atomically published result.
 from __future__ import annotations
 
 import dataclasses
-import time
 from functools import partial
 from typing import Optional
 
@@ -38,6 +37,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import multi_hashgraph, plans
 from repro.core.hashgraph import EMPTY_KEY
 from repro.core.state import TableState, Tombstones
+from repro.obs.tracing import process_tracer
 from repro.utils.compat import shard_map
 
 
@@ -402,30 +402,23 @@ def fold_oldest(state: TableState, k: int, *, metrics=None) -> TableState:
     if k <= 0:
         return state
     table = state.table
-    t0 = time.perf_counter()
-    rows_before = allocated_rows(state)
-    if not state.coherent:
-        out = table.compact(state)
-        record_fold(
-            metrics,
-            kind="full",
-            seconds=time.perf_counter() - t0,
-            rows_before=rows_before,
-            rows_after=allocated_rows(out),
-        )
-        return out
-    new_base, new_ts = exec_fold(table, state, k=k)
-    out = TableState(
-        base=new_base,
-        deltas=state.deltas[k:],
-        tombstones=new_ts,
-        table=table,
-        coherent=True,
-    )
+    with process_tracer().span("table.fold") as span:
+        rows_before = allocated_rows(state)
+        if state.coherent:
+            new_base, new_ts = exec_fold(table, state, k=k)
+            out = TableState(
+                base=new_base,
+                deltas=state.deltas[k:],
+                tombstones=new_ts,
+                table=table,
+                coherent=True,
+            )
+        else:
+            out = table.compact(state)
     record_fold(
         metrics,
-        kind="fold",
-        seconds=time.perf_counter() - t0,
+        kind="fold" if state.coherent else "full",
+        seconds=span.seconds,
         rows_before=rows_before,
         rows_after=allocated_rows(out),
     )

@@ -16,6 +16,11 @@ Build (:func:`build_sharded`) follows the paper's four phases:
 Query (:func:`query_sharded`) is the paper's query: route query keys with
 the *same* splits, intersect against the local table, route counts back.
 
+Each step runs under the ``jax.named_scope`` of its device stage
+(``repro.obs.tracing.STAGES``): ``route``, ``locate``, ``gather``,
+``return`` and ``expand`` on the read and join paths, ``build.*`` in the
+build, so a profiler trace's ops name the stage that emitted them.
+
 Static-shape note: a device's hash-range width ``splits[d+1]-splits[d]`` is
 data-dependent, but XLA needs a static local table size.  We allocate
 ``local_range_cap = ceil(HR/D) * range_slack`` buckets and clamp rebased
@@ -35,6 +40,7 @@ import jax.numpy as jnp
 
 from repro.core import exchange, hashing, hashgraph, partition
 from repro.core.hashgraph import EMPTY_KEY, HashGraph
+from repro.obs.tracing import stage
 from repro.utils import cdiv
 
 
@@ -158,57 +164,61 @@ def build_sharded(
     with each ``dest_offset`` (see ``query_sharded``).
     """
     axis_names = tuple(axis_names)
-    keys = keys.astype(jnp.uint32)
     n_local = keys.shape[0]
     num_devices = exchange.device_count(axis_names)
-    if values is None:
-        # Globalize the default payload: original row id within this shard,
-        # offset by the shard's rank so values are unique across devices.
-        values = exchange.my_rank(axis_names) * n_local + jnp.arange(
-            n_local, dtype=jnp.int32
-        )
-    is_pad = hashgraph.is_empty_key(keys)
 
     # ---- Phase 1: partitioning --------------------------------------------
-    h = hashing.hash_to_buckets(keys, hash_range, seed=seed)
-    if hash_splits is None:
-        bins_g = num_bins or partition.choose_num_bins(hash_range, num_devices)
-        hist = partition.local_bin_histogram(h, bins_g, hash_range, valid=~is_pad)
-        ghist = jax.lax.psum(hist, axis_names)
-        splits = partition.balanced_hash_splits(ghist, num_devices, hash_range)
-    else:
-        splits = hash_splits.astype(jnp.int32)  # frozen: no collective round
+    with stage("build.partition"):
+        keys = keys.astype(jnp.uint32)
+        if values is None:
+            # Globalize the default payload: original row id within this
+            # shard, offset by the shard's rank so values are unique across
+            # devices.
+            values = exchange.my_rank(axis_names) * n_local + jnp.arange(
+                n_local, dtype=jnp.int32
+            )
+        is_pad = hashgraph.is_empty_key(keys)
+        h = hashing.hash_to_buckets(keys, hash_range, seed=seed)
+        if hash_splits is None:
+            bins_g = num_bins or partition.choose_num_bins(hash_range, num_devices)
+            hist = partition.local_bin_histogram(h, bins_g, hash_range, valid=~is_pad)
+            ghist = jax.lax.psum(hist, axis_names)
+            splits = partition.balanced_hash_splits(ghist, num_devices, hash_range)
+        else:
+            splits = hash_splits.astype(jnp.int32)  # frozen: no collective round
 
-    # ---- Phase 2: reorganization ------------------------------------------
-    dest = partition.destination_of(h, splits)
-    if dest_offsets is not None:
-        dest = (dest + dest_offsets.astype(jnp.int32)) % num_devices
-    # Sentinels route round-robin (all EMPTY rows hash identically — sending
-    # them by hash would funnel every one to a single owner's slot).
-    dest = jnp.where(
-        is_pad, jnp.arange(n_local, dtype=jnp.int32) % num_devices, dest
-    )
+    with stage("build.exchange"):
+        # ---- Phase 2: reorganization --------------------------------------
+        dest = partition.destination_of(h, splits)
+        if dest_offsets is not None:
+            dest = (dest + dest_offsets.astype(jnp.int32)) % num_devices
+        # Sentinels route round-robin (all EMPTY rows hash identically —
+        # sending them by hash would funnel every one to a single owner's
+        # slot).
+        dest = jnp.where(
+            is_pad, jnp.arange(n_local, dtype=jnp.int32) % num_devices, dest
+        )
 
-    # ---- Phase 3: movement -------------------------------------------------
-    if capacity is None:
-        capacity = default_capacity(n_local, num_devices, capacity_slack)
-    (rkeys, rvalues), route = exchange.dispatch(
-        (keys, values),
-        dest,
-        axis_names,
-        capacity,
-        fills=(jnp.uint32(EMPTY_KEY), jnp.int32(-1)),
-        count_mask=~is_pad,
-    )
+        # ---- Phase 3: movement --------------------------------------------
+        if capacity is None:
+            capacity = default_capacity(n_local, num_devices, capacity_slack)
+        (rkeys, rvalues), route = exchange.dispatch(
+            (keys, values),
+            dest,
+            axis_names,
+            capacity,
+            fills=(jnp.uint32(EMPTY_KEY), jnp.int32(-1)),
+            count_mask=~is_pad,
+        )
 
     # ---- Phase 4: local HashGraph creation ---------------------------------
     if local_range_cap is None:
         local_cap = int(cdiv(hash_range, num_devices) * range_slack)
     else:
         local_cap = int(local_range_cap)
-    rank = exchange.my_rank(axis_names)
-    lo = splits[rank]
-    buckets = _local_buckets(rkeys, lo, hash_range, local_cap, seed, bucket_stride)
+    with stage("build.sort"):
+        lo = splits[exchange.my_rank(axis_names)]
+        buckets = _local_buckets(rkeys, lo, hash_range, local_cap, seed, bucket_stride)
     local = hashgraph.build_from_buckets(
         rkeys,
         buckets,
@@ -218,10 +228,12 @@ def build_sharded(
         sort_within_bucket=True,
         fingerprint=fingerprint,
     )
+    with stage("build.exchange"):
+        num_dropped = jax.lax.psum(route.num_dropped, axis_names)
     return DistributedHashGraph(
         local=local,
         hash_splits=splits,
-        num_dropped=jax.lax.psum(route.num_dropped, axis_names),
+        num_dropped=num_dropped,
         hash_range=hash_range,
         seed=seed,
         local_range_cap=local_cap,
@@ -258,20 +270,20 @@ def _route_queries_once(
     capacity.
     """
     axis_names = dhg.axis_names
-    queries = queries.astype(jnp.uint32)
     num_devices = exchange.device_count(axis_names)
-
-    h = hashing.hash_to_buckets(queries, dhg.hash_range, seed=dhg.seed)
-    dest = partition.destination_of(h, dhg.hash_splits)
-    if dest_offset:
-        dest = (dest + jnp.int32(dest_offset)) % num_devices
     capacity = default_capacity(queries.shape[0], num_devices, capacity_slack)
-    (rq,), route = exchange.dispatch(
-        (queries,), dest, axis_names, capacity, fills=(jnp.uint32(EMPTY_KEY),)
-    )
-    lo = dhg.hash_splits[exchange.my_rank(axis_names)]
-    rh = hashing.hash_to_buckets(rq, dhg.hash_range, seed=dhg.seed)
-    is_pad = hashgraph.is_empty_key(rq)
+    with stage("route"):
+        queries = queries.astype(jnp.uint32)
+        h = hashing.hash_to_buckets(queries, dhg.hash_range, seed=dhg.seed)
+        dest = partition.destination_of(h, dhg.hash_splits)
+        if dest_offset:
+            dest = (dest + jnp.int32(dest_offset)) % num_devices
+        (rq,), route = exchange.dispatch(
+            (queries,), dest, axis_names, capacity, fills=(jnp.uint32(EMPTY_KEY),)
+        )
+        lo = dhg.hash_splits[exchange.my_rank(axis_names)]
+        rh = hashing.hash_to_buckets(rq, dhg.hash_range, seed=dhg.seed)
+        is_pad = hashgraph.is_empty_key(rq)
     return rq, route, rh, is_pad, lo, capacity
 
 
@@ -292,9 +304,10 @@ def _route_queries(
     rq, route, rh, is_pad, lo, capacity = _route_queries_once(
         dhg, queries, capacity_slack, dest_offset
     )
-    rbuckets = _rebase_buckets(
-        rh, is_pad, lo, dhg.local_range_cap, dhg.bucket_stride
-    )
+    with stage("route"):
+        rbuckets = _rebase_buckets(
+            rh, is_pad, lo, dhg.local_range_cap, dhg.bucket_stride
+        )
     return rq, route, rbuckets, capacity
 
 
@@ -381,15 +394,17 @@ def query_sharded(
     rq, route, rbuckets, _ = _route_queries(
         dhg, queries, capacity_slack, dest_offset
     )
-    if paper_faithful_probe:
-        counts = hashgraph.query_count_probe(
-            dhg.local, rq, max_probe=max_probe, buckets=rbuckets
-        )
-    else:
-        counts = hashgraph.query_count_sorted(dhg.local, rq, buckets=rbuckets)
-    # Padding slots probe the trash bucket; force their count to zero anyway.
-    counts = _mask_counts(counts, rq, tombstones, layer_epoch)
-    return exchange.combine(counts, route, axis_names, fill=jnp.int32(0))
+    with stage("locate"):
+        if paper_faithful_probe:
+            counts = hashgraph.query_count_probe(
+                dhg.local, rq, max_probe=max_probe, buckets=rbuckets
+            )
+        else:
+            counts = hashgraph.query_count_sorted(dhg.local, rq, buckets=rbuckets)
+        # Padding slots probe the trash bucket; force their count to zero.
+        counts = _mask_counts(counts, rq, tombstones, layer_epoch)
+    with stage("return"):
+        return exchange.combine(counts, route, axis_names, fill=jnp.int32(0))
 
 
 def query_layers_sharded(
@@ -422,7 +437,7 @@ def query_layers_sharded(
     if not fused:
         total = jnp.zeros(queries.shape[0], jnp.int32)
         for epoch, layer in enumerate(layers):
-            total = total + query_sharded(
+            counts = query_sharded(
                 layer,
                 queries,
                 tombstones=tombstones,
@@ -432,26 +447,32 @@ def query_layers_sharded(
                 max_probe=max_probe,
                 dest_offset=dest_offset,
             )
+            with stage("return"):
+                total = total + counts
         return total
 
     base = layers[0]
     rq, route, rh, is_pad, lo, _ = _route_queries_once(
         base, queries, capacity_slack, dest_offset
     )
-    match_e = _tombstone_epochs(rq, tombstones)
-    rfp = _routed_fingerprints(layers, rq)
-    total = jnp.zeros(rq.shape[0], jnp.int32)
-    for epoch, layer in enumerate(layers):
-        rb = _rebase_buckets(rh, is_pad, lo, layer.local_range_cap, layer.bucket_stride)
-        if paper_faithful_probe:
-            c = hashgraph.query_count_probe(
-                layer.local, rq, max_probe=max_probe, buckets=rb
+    with stage("locate"):
+        match_e = _tombstone_epochs(rq, tombstones)
+        rfp = _routed_fingerprints(layers, rq)
+        total = jnp.zeros(rq.shape[0], jnp.int32)
+        for epoch, layer in enumerate(layers):
+            rb = _rebase_buckets(
+                rh, is_pad, lo, layer.local_range_cap, layer.bucket_stride
             )
-        else:
-            c = hashgraph.query_count_sorted(layer.local, rq, buckets=rb, qfp=rfp)
-        total = total + _mask_counts(c, rq, tombstones, epoch, match_e)
+            if paper_faithful_probe:
+                c = hashgraph.query_count_probe(
+                    layer.local, rq, max_probe=max_probe, buckets=rb
+                )
+            else:
+                c = hashgraph.query_count_sorted(layer.local, rq, buckets=rb, qfp=rfp)
+            total = total + _mask_counts(c, rq, tombstones, epoch, match_e)
     # One merged return trip carries the whole stack's counts.
-    return exchange.combine(total, route, base.axis_names, fill=jnp.int32(0))
+    with stage("return"):
+        return exchange.combine(total, route, base.axis_names, fill=jnp.int32(0))
 
 
 def contains_sharded(
@@ -568,31 +589,37 @@ def _retrieve_runs(
     num_devices = exchange.device_count(axis_names)
 
     rq, route, rbuckets, capacity = _route_queries(dhg, queries, capacity_slack)
-    run_starts, run_counts = hashgraph.query_locate(dhg.local, rq, buckets=rbuckets)
-    run_counts = _mask_counts(run_counts, rq, tombstones, layer_epoch)
+    with stage("locate"):
+        run_starts, run_counts = hashgraph.query_locate(
+            dhg.local, rq, buckets=rbuckets
+        )
+        run_counts = _mask_counts(run_counts, rq, tombstones, layer_epoch)
 
     # Owner side: one packed segment of matched values per source device.
-    starts_b = run_starts.reshape(num_devices, capacity)
-    counts_b = run_counts.reshape(num_devices, capacity)
-    if use_kernel:
-        from repro.kernels import ops as kernel_ops
+    with stage("gather"):
+        starts_b = run_starts.reshape(num_devices, capacity)
+        counts_b = run_counts.reshape(num_devices, capacity)
+        if use_kernel:
+            from repro.kernels import ops as kernel_ops
 
-        # Fused launch: one grid over (sources, capacity tiles) instead of
-        # one pallas_call per source block.
-        _, _, seg_values, owner_dropped = kernel_ops.csr_gather_batched(
-            starts_b, counts_b, dhg.local.values, capacity=seg_capacity
-        )
-    else:
-        _, _, seg_values, seg_dropped = jax.vmap(
-            lambda s, c: hashgraph.csr_gather(s, c, dhg.local.values, seg_capacity)
-        )(starts_b, counts_b)
-        owner_dropped = jnp.sum(seg_dropped)
+            # Fused launch: one grid over (sources, capacity tiles) instead
+            # of one pallas_call per source block.
+            _, _, seg_values, owner_dropped = kernel_ops.csr_gather_batched(
+                starts_b, counts_b, dhg.local.values, capacity=seg_capacity
+            )
+        else:
+            _, _, seg_values, seg_dropped = jax.vmap(
+                lambda s, c: hashgraph.csr_gather(s, c, dhg.local.values, seg_capacity)
+            )(starts_b, counts_b)
+            owner_dropped = jnp.sum(seg_dropped)
 
     # Querier side: segments + run lengths come home.
-    counts, starts, seg_flat = exchange.combine_ragged(
-        seg_values, run_counts, route, axis_names
-    )
-    return counts, starts, seg_flat, owner_dropped + route.num_dropped
+    with stage("return"):
+        counts, starts, seg_flat = exchange.combine_ragged(
+            seg_values, run_counts, route, axis_names
+        )
+        dropped = owner_dropped + route.num_dropped
+    return counts, starts, seg_flat, dropped
 
 
 def _layer_run_descriptors(
@@ -613,19 +640,22 @@ def _layer_run_descriptors(
     Returns ``(starts, counts, tables)``: ``(L, R)`` stacked descriptors
     (``R`` = routed slots) addressing ``jnp.concatenate(tables)``.
     """
-    match_e = _tombstone_epochs(rq, tombstones)
-    rfp = _routed_fingerprints(layers, rq)
-    starts_l, counts_l, tables = [], [], []
-    off = 0
-    for epoch, layer in enumerate(layers):
-        rb = _rebase_buckets(rh, is_pad, lo, layer.local_range_cap, layer.bucket_stride)
-        s, c = hashgraph.query_locate(layer.local, rq, buckets=rb, qfp=rfp)
-        c = _mask_counts(c, rq, tombstones, epoch, match_e)
-        starts_l.append(s + off)
-        counts_l.append(c)
-        tables.append(layer.local.values)
-        off += layer.local.values.shape[0]
-    return jnp.stack(starts_l), jnp.stack(counts_l), tuple(tables)
+    with stage("locate"):
+        match_e = _tombstone_epochs(rq, tombstones)
+        rfp = _routed_fingerprints(layers, rq)
+        starts_l, counts_l, tables = [], [], []
+        off = 0
+        for epoch, layer in enumerate(layers):
+            rb = _rebase_buckets(
+                rh, is_pad, lo, layer.local_range_cap, layer.bucket_stride
+            )
+            s, c = hashgraph.query_locate(layer.local, rq, buckets=rb, qfp=rfp)
+            c = _mask_counts(c, rq, tombstones, epoch, match_e)
+            starts_l.append(s + off)
+            counts_l.append(c)
+            tables.append(layer.local.values)
+            off += layer.local.values.shape[0]
+        return jnp.stack(starts_l), jnp.stack(counts_l), tuple(tables)
 
 
 def _csr_gather_layers_ref(starts, counts, tables, capacity: int):
@@ -683,37 +713,40 @@ def _retrieve_parts_fused(
     )
     # (L, D*cap) -> (L, D, cap): the gather's source axis is the dispatching
     # device, its row axis the slot-major/layer-minor interleaved runs.
-    starts_lsn = starts_lr.reshape(nlayers, num_devices, capacity)
-    counts_lsn = counts_lr.reshape(nlayers, num_devices, capacity)
-    if use_kernel:
-        from repro.kernels import ops as kernel_ops
+    with stage("gather"):
+        starts_lsn = starts_lr.reshape(nlayers, num_devices, capacity)
+        counts_lsn = counts_lr.reshape(nlayers, num_devices, capacity)
+        if use_kernel:
+            from repro.kernels import ops as kernel_ops
 
-        seg_values, owner_dropped = kernel_ops.csr_gather_layers(
-            starts_lsn, counts_lsn, tables, capacity=seg_capacity
-        )
-    else:
-        seg_values, owner_dropped = _csr_gather_layers_ref(
-            starts_lsn, counts_lsn, tables, seg_capacity
-        )
+            seg_values, owner_dropped = kernel_ops.csr_gather_layers(
+                starts_lsn, counts_lsn, tables, capacity=seg_capacity
+            )
+        else:
+            seg_values, owner_dropped = _csr_gather_layers_ref(
+                starts_lsn, counts_lsn, tables, seg_capacity
+            )
 
     # One ragged return: per-slot totals over the stack reconstruct, on the
     # querier, exactly the interleaved offsets the owner packed with.
-    slot_totals = jnp.sum(counts_lr, axis=0)
-    layer_breakdown = None
-    if per_layer:
-        counts, starts, seg_flat, layer_breakdown = exchange.combine_ragged(
-            seg_values, slot_totals, route, axis_names, layer_counts=counts_lr
+    with stage("return"):
+        slot_totals = jnp.sum(counts_lr, axis=0)
+        layer_breakdown = None
+        if per_layer:
+            counts, starts, seg_flat, layer_breakdown = exchange.combine_ragged(
+                seg_values, slot_totals, route, axis_names, layer_counts=counts_lr
+            )
+        else:
+            counts, starts, seg_flat = exchange.combine_ragged(
+                seg_values, slot_totals, route, axis_names
+            )
+    with stage("expand"):
+        offsets, slot_rows, values, out_dropped = _csr_gather_any(
+            starts, counts, seg_flat, out_capacity, use_kernel
         )
-    else:
-        counts, starts, seg_flat = exchange.combine_ragged(
-            seg_values, slot_totals, route, axis_names
+        num_dropped = jax.lax.psum(
+            owner_dropped + route.num_dropped + out_dropped, axis_names
         )
-    offsets, slot_rows, values, out_dropped = _csr_gather_any(
-        starts, counts, seg_flat, out_capacity, use_kernel
-    )
-    num_dropped = jax.lax.psum(
-        owner_dropped + route.num_dropped + out_dropped, axis_names
-    )
     return offsets, slot_rows, values, counts, num_dropped, rank, n_local, layer_breakdown
 
 
@@ -782,28 +815,30 @@ def _retrieve_parts(
             tombstones=tombstones,
             layer_epoch=epoch,
         )
-        counts_l.append(counts)
-        starts_l.append(starts + epoch * seg_flat.shape[0])
-        segs_l.append(seg_flat)
-        dropped = dropped + drop
+        with stage("expand"):
+            counts_l.append(counts)
+            starts_l.append(starts + epoch * seg_flat.shape[0])
+            segs_l.append(seg_flat)
+            dropped = dropped + drop
 
-    seg_all = segs_l[0] if nlayers == 1 else jnp.concatenate(segs_l, axis=0)
-    counts_il = jnp.stack(counts_l, axis=1).reshape(n_local * nlayers)
-    starts_il = jnp.stack(starts_l, axis=1).reshape(n_local * nlayers)
-    offsets_il, slot_rows, values, out_dropped = _csr_gather_any(
-        starts_il, counts_il, seg_all, out_capacity, use_kernel
-    )
-    offsets = offsets_il[::nlayers]  # every L-th interleaved offset
-    counts = counts_il.reshape(n_local, nlayers).sum(axis=1).astype(jnp.int32)
-    query_idx = jnp.where(slot_rows >= 0, slot_rows // nlayers, jnp.int32(-1))
-    # Overflow indicator, not an exact loss count: the stages can
-    # double-count one missing result (owner segment + querier output), and
-    # route drops count lost query *rows* whose result count is unknown.
-    # Zero iff nothing anywhere was truncated.
-    num_dropped = jax.lax.psum(dropped + out_dropped, axis_names)
-    layer_breakdown = (
-        jnp.stack(counts_l, axis=1).astype(jnp.int32) if per_layer else None
-    )
+    with stage("expand"):
+        seg_all = segs_l[0] if nlayers == 1 else jnp.concatenate(segs_l, axis=0)
+        counts_il = jnp.stack(counts_l, axis=1).reshape(n_local * nlayers)
+        starts_il = jnp.stack(starts_l, axis=1).reshape(n_local * nlayers)
+        offsets_il, slot_rows, values, out_dropped = _csr_gather_any(
+            starts_il, counts_il, seg_all, out_capacity, use_kernel
+        )
+        offsets = offsets_il[::nlayers]  # every L-th interleaved offset
+        counts = counts_il.reshape(n_local, nlayers).sum(axis=1).astype(jnp.int32)
+        query_idx = jnp.where(slot_rows >= 0, slot_rows // nlayers, jnp.int32(-1))
+        # Overflow indicator, not an exact loss count: the stages can
+        # double-count one missing result (owner segment + querier output),
+        # and route drops count lost query *rows* whose result count is
+        # unknown.  Zero iff nothing anywhere was truncated.
+        num_dropped = jax.lax.psum(dropped + out_dropped, axis_names)
+        layer_breakdown = (
+            jnp.stack(counts_l, axis=1).astype(jnp.int32) if per_layer else None
+        )
     return offsets, query_idx, values, counts, num_dropped, rank, n_local, layer_breakdown
 
 
@@ -921,13 +956,14 @@ def inner_join_layers_sharded(
         tombstones=tombstones,
         fused=fused,
     )
-    globl = rank.astype(jnp.int32) * n_local + query_idx
-    query_idx = jnp.where(query_idx >= 0, globl, jnp.int32(-1))
-    num_results = jnp.minimum(jnp.sum(counts), out_capacity).astype(jnp.int32)
+    with stage("expand"):
+        globl = rank.astype(jnp.int32) * n_local + query_idx
+        query_idx = jnp.where(query_idx >= 0, globl, jnp.int32(-1))
+        num_results = jnp.minimum(jnp.sum(counts), out_capacity).astype(jnp.int32)[None]
     return ShardJoin(
         query_idx=query_idx,
         values=values,
-        num_results=num_results[None],
+        num_results=num_results,
         num_dropped=num_dropped,
     )
 

@@ -45,6 +45,7 @@ from repro.core.multi_hashgraph import (
     ShardRetrieval,
 )
 from repro.core.state import TableState, Tombstones, as_state
+from repro.obs.tracing import process_tracer, stage
 from repro.utils.compat import shard_map
 
 
@@ -118,6 +119,12 @@ def _in_spec(table):
     return P(tuple(table.axis_names))
 
 
+def _tombstone_index(st: TableState):
+    """The sorted tombstone index every read masks with (stage ``locate``)."""
+    with stage("locate"):
+        return st.tombstones.index()
+
+
 @partial(jax.jit, static_argnums=(0,), static_argnames=("dest_offset",))
 def exec_query(
     table, state: TableState, queries: jax.Array, *, dest_offset: int = 0
@@ -134,7 +141,7 @@ def exec_query(
         return multi_hashgraph.query_layers_sharded(
             st.layers,
             q,
-            tombstones=st.tombstones.index(),
+            tombstones=_tombstone_index(st),
             fused=_fused(table, st),
             capacity_slack=table.capacity_slack,
             paper_faithful_probe=table.paper_faithful_probe,
@@ -213,7 +220,7 @@ def exec_retrieve(
             out_capacity=out_capacity,
             capacity_slack=table.capacity_slack,
             use_kernel=table.use_kernel,
-            tombstones=st.tombstones.index(),
+            tombstones=_tombstone_index(st),
             fused=_fused(table, st),
             per_layer_counts=per_layer_counts,
         )
@@ -252,7 +259,7 @@ def exec_join(
             out_capacity=out_capacity,
             capacity_slack=table.capacity_slack,
             use_kernel=table.use_kernel,
-            tombstones=st.tombstones.index(),
+            tombstones=_tombstone_index(st),
             fused=_fused(table, st),
         )
 
@@ -274,7 +281,7 @@ def exec_plan_caps(table, state: TableState, queries: jax.Array):
             st.layers,
             q,
             capacity_slack=table.capacity_slack,
-            tombstones=st.tombstones.index(),
+            tombstones=_tombstone_index(st),
             fused=_fused(table, st),
         )
 
@@ -375,7 +382,8 @@ def state_signature(state: TableState) -> tuple:
 class CompiledPlan:
     """An AOT-compiled ``(state, queries) -> result`` executable.
 
-    Built by :meth:`QueryPlan.compile` / :meth:`RetrievePlan.compile` —
+    Built by :meth:`QueryPlan.compile`, :meth:`RetrievePlan.compile` or
+    :meth:`JoinPlan.compile` —
     the ``jit(...).lower(...).compile()`` idiom: the trace/compile cost is
     paid at *construction*, and calls run the XLA executable directly (the
     jit dispatch cache is never consulted, so a warmed serving path does
@@ -385,7 +393,7 @@ class CompiledPlan:
     """
 
     compiled: object  # jax.stages.Compiled
-    kind: str  # "query" | "retrieve"
+    kind: str  # "query" | "retrieve" | "join"
     num_queries: int
     signature: tuple  # state_signature the executable was lowered against
 
@@ -433,8 +441,9 @@ class QueryPlan(_PlanBase):
     num_queries: Optional[int] = None
 
     def __call__(self, state, queries) -> jax.Array:
-        st, q = self._prep(state, queries)
-        return exec_query(self.table, st, q)
+        with process_tracer().span("plan.query"):
+            st, q = self._prep(state, queries)
+            return exec_query(self.table, st, q)
 
     def join_size(self, state, queries) -> jax.Array:
         """Global join cardinality under the same plan (replicated ())."""
@@ -476,15 +485,16 @@ class RetrievePlan(_PlanBase):
     per_layer_counts: bool = False
 
     def __call__(self, state, queries) -> ShardRetrieval:
-        st, q = self._prep(state, queries)
-        return exec_retrieve(
-            self.table,
-            st,
-            q,
-            out_capacity=self.out_capacity,
-            seg_capacity=self.seg_capacity,
-            per_layer_counts=self.per_layer_counts,
-        )
+        with process_tracer().span("plan.retrieve"):
+            st, q = self._prep(state, queries)
+            return exec_retrieve(
+                self.table,
+                st,
+                q,
+                out_capacity=self.out_capacity,
+                seg_capacity=self.seg_capacity,
+                per_layer_counts=self.per_layer_counts,
+            )
 
     def lower(self, state, queries=None):
         """AOT-lower the retrieve executor (capacities baked in) against
@@ -521,11 +531,35 @@ class JoinPlan(_PlanBase):
     seg_capacity: int
 
     def __call__(self, state, queries) -> ShardJoin:
-        st, q = self._prep(state, queries)
-        return exec_join(
+        with process_tracer().span("plan.join"):
+            st, q = self._prep(state, queries)
+            return exec_join(
+                self.table,
+                st,
+                q,
+                out_capacity=self.out_capacity,
+                seg_capacity=self.seg_capacity,
+            )
+
+    def lower(self, state, queries=None):
+        """AOT-lower the join executor (capacities baked in) against
+        ``state``'s structure; see :meth:`QueryPlan.lower`."""
+        st = as_state(self.table, state)
+        return exec_join.lower(
             self.table,
             st,
-            q,
+            self._proto_q(queries),
             out_capacity=self.out_capacity,
             seg_capacity=self.seg_capacity,
+        )
+
+    def compile(self, state, queries=None) -> CompiledPlan:
+        """AOT-compile: see :meth:`QueryPlan.compile`."""
+        st = as_state(self.table, state)
+        q = self._proto_q(queries)
+        return CompiledPlan(
+            compiled=self.lower(st, q).compile(),
+            kind="join",
+            num_queries=q.shape[0],
+            signature=state_signature(st),
         )

@@ -54,6 +54,7 @@ from repro.core.multi_hashgraph import (
 from repro.core.plans import JoinPlan, QueryPlan, RetrievePlan
 from repro.core.schema import TableSchema
 from repro.core.state import TableState, as_state, empty_tombstones
+from repro.obs.tracing import process_tracer
 from repro.utils import cdiv as _cdiv
 
 
@@ -245,18 +246,29 @@ class DistributedHashTable:
            :class:`TableState` supporting insert/delete/compact.  ``build``
            returns the bare ``DistributedHashGraph`` for older call sites.
         """
+        if values is None and self.schema.value_cols != 1:
+            raise ValueError(
+                f"schema has {self.schema.value_cols} value columns; "
+                "pass explicit values (the row-id default is 1-column)"
+            )
+        tracer = process_tracer()
         sharding = self.key_sharding()
-        keys = self.schema.pack_keys(keys, sharding)
-        if values is None:
-            if self.schema.value_cols != 1:
-                raise ValueError(
-                    f"schema has {self.schema.value_cols} value columns; "
-                    "pass explicit values (the row-id default is 1-column)"
-                )
-            return self._build_jit(keys, hash_range=self.hash_range)
-        return self._build_values_jit(
-            keys, self.schema.pack_values(values, sharding), hash_range=self.hash_range
-        )
+        with tracer.span("table.build"):
+            with tracer.span("table.build.pack"):
+                keys = self.schema.pack_keys(keys, sharding)
+                if values is not None:
+                    values = self.schema.pack_values(values, sharding)
+            # Launch to ready; only a traced build waits for the device here.
+            with tracer.span("table.build.run"):
+                if values is None:
+                    graph = self._build_jit(keys, hash_range=self.hash_range)
+                else:
+                    graph = self._build_values_jit(
+                        keys, values, hash_range=self.hash_range
+                    )
+                if tracer.enabled and not isinstance(keys, jax.core.Tracer):
+                    jax.block_until_ready(graph)
+        return graph
 
     def init(self, keys, values=None) -> TableState:
         """Build and wrap into a versioned :class:`TableState`.

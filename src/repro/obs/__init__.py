@@ -6,7 +6,8 @@ The serving stack's single source of truth for measurement:
   and log-bucketed latency histograms; one-lock-consistent snapshots.
 * :mod:`repro.obs.tracing` — per-request spans through the async pipeline
   (admission → linger → dispatch → device → scatter) with a bounded ring
-  of recent full traces.
+  of recent full traces; host spans of the program's steps on the
+  profiler's clock; the device stages' scope names.
 * :mod:`repro.obs.profiling` — jaxpr-walking collective accountant plus
   XLA cost-analysis integration, one :class:`ExecutorCost` per compiled
   executor in the AOT grid.
@@ -43,7 +44,15 @@ from repro.obs.registry import (
     MetricsRegistry,
     RegistrySnapshot,
 )
-from repro.obs.tracing import PHASES, Trace, Tracer
+from repro.obs.tracing import (
+    PHASES,
+    STAGES,
+    SpanRecord,
+    Trace,
+    Tracer,
+    process_tracer,
+    stage,
+)
 
 __all__ = [
     "COLLECTIVE_PRIMITIVES",
@@ -56,13 +65,17 @@ __all__ = [
     "MetricsRegistry",
     "PHASES",
     "RegistrySnapshot",
+    "STAGES",
+    "SpanRecord",
     "Trace",
     "Tracer",
     "collective_profile",
     "count_primitive",
     "parse_prometheus",
+    "process_tracer",
     "profile_executor",
     "render_jsonl",
     "render_prometheus",
+    "stage",
     "write_jsonl",
 ]

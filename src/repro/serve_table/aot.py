@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Sequence
 
@@ -42,6 +41,7 @@ from repro.core import maintenance
 from repro.core.hashgraph import EMPTY_KEY
 from repro.core.plans import CompiledPlan, state_signature
 from repro.core.state import TableState
+from repro.obs.tracing import process_tracer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -232,14 +232,62 @@ def warm_server(
     re-warming replaces the grid (the server registry's AOT counters keep
     accumulating across re-warms).
     """
-    table = server.table
     if server.write_bucket is None:
         raise ValueError(
             "AOT warmup needs a shape-stable write path: construct the "
             "TableServer with write_bucket=<pow2> so every insert delta "
             "shares one geometry"
         )
-    t0 = time.perf_counter()
+    with process_tracer().span("server.warm") as span:
+        grid = _warm_grid(
+            server,
+            buckets=buckets,
+            depths=depths,
+            fold_horizon=fold_horizon,
+            retrieve_caps=retrieve_caps,
+            workers=workers,
+            profile=profile,
+        )
+    grid._meta["compile_seconds"] = span.seconds
+    registry = getattr(server, "metrics_registry", None)
+    if registry is not None:
+        grid.bind_registry(registry)
+        registry.gauge(
+            "aot_entries", help="Compiled executables held by the AOT grid."
+        ).set(len(grid))
+        registry.gauge(
+            "aot_compile_seconds", help="Wall-clock cost of the last warmup."
+        ).set(span.seconds)
+        for cost in grid.profiles:
+            labels = {
+                "kind": cost.kind,
+                "bucket": cost.bucket,
+                "depth": cost.depth,
+            }
+            registry.gauge(
+                "executor_all_to_alls",
+                labels=labels,
+                help="all_to_all primitives per executor (jaxpr accountant).",
+            ).set(cost.all_to_alls)
+            registry.gauge(
+                "executor_collective_bytes",
+                labels=labels,
+                help="Per-device bytes moved through collectives per call.",
+            ).set(cost.total_collective_bytes)
+    server.batcher.executors = grid
+    # Seed the batcher's retrieve working caps so warmed buckets skip the
+    # planning round and land on the compiled executables.
+    for b, caps in grid._retrieve_caps.items():
+        server.batcher._caps.setdefault(b, caps)
+    return grid.stats()
+
+
+def _warm_grid(
+    server, *, buckets, depths, fold_horizon, retrieve_caps, workers, profile
+) -> ExecutorGrid:
+    """The body of :func:`warm_server`: prototype states, lowering,
+    compilation and profiling of the grid (not yet attached)."""
+    table = server.table
     state0 = server.current().state
     policy = server.policy
     trigger = policy.max_delta_depth
@@ -337,39 +385,8 @@ def warm_server(
         buckets=buckets,
         depths=depths,
         fold_horizon=fold_horizon,
-        compile_seconds=time.perf_counter() - t0,
     )
-    registry = getattr(server, "metrics_registry", None)
-    if registry is not None:
-        grid.bind_registry(registry)
-        registry.gauge(
-            "aot_entries", help="Compiled executables held by the AOT grid."
-        ).set(len(grid))
-        registry.gauge(
-            "aot_compile_seconds", help="Wall-clock cost of the last warmup."
-        ).set(time.perf_counter() - t0)
-        for cost in grid.profiles:
-            labels = {
-                "kind": cost.kind,
-                "bucket": cost.bucket,
-                "depth": cost.depth,
-            }
-            registry.gauge(
-                "executor_all_to_alls",
-                labels=labels,
-                help="all_to_all primitives per executor (jaxpr accountant).",
-            ).set(cost.all_to_alls)
-            registry.gauge(
-                "executor_collective_bytes",
-                labels=labels,
-                help="Per-device bytes moved through collectives per call.",
-            ).set(cost.total_collective_bytes)
-    server.batcher.executors = grid
-    # Seed the batcher's retrieve working caps so warmed buckets skip the
-    # planning round and land on the compiled executables.
-    for b, caps in grid._retrieve_caps.items():
-        server.batcher._caps.setdefault(b, caps)
-    return grid.stats()
+    return grid
 
 
 def _profile_grid(table, grid, protos, buckets, retrieve_caps) -> tuple:

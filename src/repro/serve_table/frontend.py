@@ -300,7 +300,10 @@ class AsyncFrontend:
     ``min_bucket``), ``default_deadline`` the per-request dispatch
     deadline when the caller doesn't pass one.  ``write_backlog`` bounds
     the server's write queue as seen through this front end —
-    ``submit_insert``/``submit_delete`` block while it is full.
+    ``submit_insert``/``submit_delete`` block while it is full.  With
+    ``tracing`` on, each batch also shows in a profiler trace as the host
+    annotations ``frontend.dispatch``, ``frontend.wait`` and
+    ``frontend.scatter``.
 
     Lifecycle: ``start()`` launches the dispatcher + scatter threads (and
     the server's embedded writer loop unless it is already running);
@@ -507,10 +510,11 @@ class AsyncFrontend:
                 if r.trace is not None:
                     r.trace.mark("linger", now)
             try:
-                snap = self.server.current()
-                pending = self.server.batcher.dispatch_query(
-                    snap.state, [r.keys for r in batch], seqno=snap.seqno
-                )
+                with self.tracer.annotate("frontend.dispatch"):
+                    snap = self.server.current()
+                    pending = self.server.batcher.dispatch_query(
+                        snap.state, [r.keys for r in batch], seqno=snap.seqno
+                    )
             except Exception as e:  # dispatch failed: fail this batch, keep serving
                 self._fail_batch(batch, e)
                 continue
@@ -551,11 +555,13 @@ class AsyncFrontend:
                     # Split the device wait from the host-side scatter so
                     # the two phases are separately attributable; untraced
                     # batches keep the single blocking transfer.
-                    pending.wait()
+                    with self.tracer.annotate("frontend.wait"):
+                        pending.wait()
                     now = self.clock()
                     for r in traced:
                         r.trace.mark("device", now)
-                results = pending.scatter()
+                with self.tracer.annotate("frontend.scatter"):
+                    results = pending.scatter()
             except Exception as e:
                 self._fail_batch(batch, e)
                 continue

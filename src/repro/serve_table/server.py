@@ -50,6 +50,7 @@ from repro.core.hashgraph import EMPTY_KEY
 from repro.core.maintenance import CompactionPolicy, TableStats
 from repro.core.state import empty_tombstones
 from repro.obs.registry import MetricsRegistry, RegistrySnapshot
+from repro.obs.tracing import process_tracer
 from repro.serve_table.batcher import BatcherStats, MicroBatcher
 from repro.serve_table.snapshot import Snapshot, SnapshotRegistry
 
@@ -66,7 +67,6 @@ class ServerStats:
     folds: int  # incremental fold_oldest passes
     full_compacts: int  # full compact() escalations
     fold_seconds_total: float
-    last_fold_seconds: float
     fold_in_flight: bool  # a background fold is currently running
     skew_fallbacks: int  # inserts routed incoherent by the skew guard
     last_error: Optional[str]  # last write-application failure (None = healthy)
@@ -85,7 +85,9 @@ class TableServer:
     full compaction.  ``window`` is the latency/throughput knob: the
     writer applies at most ``window`` queued mutation batches per step
     before publishing, and readers using :meth:`query_many` /
-    :meth:`retrieve_many` choose their own coalescing width.
+    :meth:`retrieve_many` choose their own coalescing width.  The
+    process tracer (``repro.obs.tracing.process_tracer``) times the
+    warm-up (``server.warm``) and each fold (``server.fold``) as host spans.
     """
 
     def __init__(
@@ -168,9 +170,6 @@ class TableServer:
         )
         self._c_full_compacts = reg.counter(
             "maintenance_folds_total", labels={"kind": "full"}
-        )
-        self._g_last_fold = reg.gauge(
-            "serve_last_fold_seconds", help="Duration of the most recent fold."
         )
 
     # -- write path (admission) ----------------------------------------------
@@ -429,38 +428,36 @@ class TableServer:
 
     def _apply_fold(self, fold_fn, *, full: bool) -> None:
         """Run one timed fold of the shadow and attribute the counter."""
-        t0 = time.perf_counter()
-        rows_before = maintenance.allocated_rows(self._shadow)
-        self._shadow = fold_fn(self._shadow)
-        if full and self.write_bucket is not None:
-            # compact() resets the tombstone buffer to zero capacity when
-            # nothing was pending; shape-stable serving re-grows it
-            # immediately (clock preserved) so the state structure — and
-            # with it the AOT executor keys — stays fixed.  With pending
-            # TTL entries compact() already returned the capacity-preserving
-            # remap, which must NOT be overwritten (the entries guard rows
-            # that survived into the new base).
-            ts = self._shadow.tombstones
-            if ts.capacity != self.table.tombstone_capacity:
-                self._shadow = dataclasses.replace(
-                    self._shadow,
-                    tombstones=empty_tombstones(
-                        self.table.tombstone_capacity,
-                        self.table.schema.key_lanes,
-                        now=ts.now,
-                    ),
-                )
-        dt = time.perf_counter() - t0
+        with process_tracer().span("server.fold") as span:
+            rows_before = maintenance.allocated_rows(self._shadow)
+            self._shadow = fold_fn(self._shadow)
+            if full and self.write_bucket is not None:
+                # compact() resets the tombstone buffer to zero capacity
+                # when nothing was pending; shape-stable serving re-grows it
+                # immediately (clock preserved) so the state structure —
+                # and with it the AOT executor keys — stays fixed.  With
+                # pending TTL entries compact() already returned the
+                # capacity-preserving remap, which must NOT be overwritten
+                # (the entries guard rows that survived into the new base).
+                ts = self._shadow.tombstones
+                if ts.capacity != self.table.tombstone_capacity:
+                    self._shadow = dataclasses.replace(
+                        self._shadow,
+                        tombstones=empty_tombstones(
+                            self.table.tombstone_capacity,
+                            self.table.schema.key_lanes,
+                            now=ts.now,
+                        ),
+                    )
         # One recording site per fold: pause time, counter by kind, and
         # reclaimed rows all land in the shared registry.
         maintenance.record_fold(
             self.metrics_registry,
             kind="full" if full else "fold",
-            seconds=dt,
+            seconds=span.seconds,
             rows_before=rows_before,
             rows_after=maintenance.allocated_rows(self._shadow),
         )
-        self._g_last_fold.set(dt)
 
     def fold_async(self, k: Optional[int] = None) -> threading.Thread:
         """Start one background fold of the shadow; reads keep flowing.
@@ -703,7 +700,6 @@ class TableServer:
                 snap.value("maintenance_folds_total", {"kind": "full"})
             ),
             fold_seconds_total=fold_seconds,
-            last_fold_seconds=float(snap.value("serve_last_fold_seconds", default=0.0)),
             fold_in_flight=self.fold_in_flight,
             skew_fallbacks=self.table.skew_fallbacks - self._skew_base,
             last_error=self._last_error,

@@ -10,7 +10,9 @@ Four layers under test:
   JSONL render must emit one valid JSON object per metric with the stamp
   merged in.
 * **Tracing**: phase marks -> durations, the bounded ring, the
-  ``live()`` leak detector, and the disabled-tracer fast path.
+  ``live()`` leak detector, and the disabled-tracer fast path; host
+  spans (nesting, per-thread parents, compile children, the profiler
+  annotation) and the spans of the table, the plans and the server.
 * **Accounting + integration** (mesh): the jaxpr collective accountant
   independently re-confirms the fused two-all-to-all budget at every
   delta depth; ``TableServer.stats()`` is a registry view (no parallel
@@ -19,7 +21,10 @@ Four layers under test:
   KV cache and maintenance fold recorder feed the same registry.
 """
 import json
+import threading
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -30,6 +35,7 @@ from repro.obs import (
     MetricsRegistry,
     Tracer,
     collective_profile,
+    process_tracer,
     parse_prometheus,
     profile_executor,
     render_jsonl,
@@ -269,6 +275,169 @@ def test_tracer_abandon_and_disabled(tmp_path):
     assert tr.dump_jsonl(str(path)) == 1
     rec = json.loads(path.read_text().strip())
     assert rec["size"] == 2 and "admission" in rec["phases"]
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records entries."""
+
+    def __init__(self):
+        self.entered = []
+
+    def __call__(self, name):
+        log = self.entered
+
+        class _Annotation:
+            def __enter__(self):
+                log.append(name)
+
+            def __exit__(self, *exc):
+                return None
+
+        return _Annotation()
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    rec = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", rec)
+    return rec
+
+
+def test_tracer_span_nests_records_and_annotates(annotations):
+    clock = FakeClock()
+    reg = MetricsRegistry()
+    tr = Tracer(reg, ring=8, clock=clock)
+    with tr.span("table.build") as outer:
+        clock.t = 1.0
+        with tr.span("table.build.pack") as inner:
+            clock.t = 1.5
+        clock.t = 4.0
+    assert inner.seconds == pytest.approx(0.5)
+    assert outer.seconds == pytest.approx(4.0)
+    spans = [r.as_dict() for r in tr.recent()]
+    assert [(d["span"], d["parent"]) for d in spans] == [
+        ("table.build.pack", "table.build"),
+        ("table.build", None),
+    ]
+    assert spans[1]["start"] == 0.0 and spans[1]["end"] == 4.0
+    snap = reg.snapshot()
+    h = snap.histogram("span_seconds", {"span": "table.build"})
+    assert (h.count, h.sum) == (1, pytest.approx(4.0))
+    assert snap.histogram("span_seconds", {"span": "table.build.pack"}).sum == 0.5
+    assert annotations.entered == ["table.build", "table.build.pack"]
+    # annotate(): a profiler annotation only, nothing recorded
+    with tr.annotate("frontend.wait"):
+        pass
+    assert annotations.entered[-1] == "frontend.wait"
+    assert len(tr.recent()) == 2
+
+
+def test_tracer_span_parent_is_per_thread(annotations):
+    tr = Tracer(MetricsRegistry())
+    opened = threading.Event()
+    release = threading.Event()
+
+    def worker():
+        with tr.span("server.fold"):
+            opened.set()
+            release.wait(5)
+
+    t = threading.Thread(target=worker)
+    with tr.span("server.warm"):
+        t.start()
+        assert opened.wait(5)
+        with tr.span("plan.query"):
+            pass
+        release.set()
+        t.join()
+    parents = {r.name: r.parent for r in tr.recent()}
+    assert parents == {"server.fold": None, "plan.query": "server.warm", "server.warm": None}
+
+
+def test_tracer_span_attributes_compiles_to_the_innermost_span(annotations):
+    tr = Tracer(MetricsRegistry())
+    x = jnp.arange(7, dtype=jnp.int32)
+    with tr.span("plan.join"):
+        with tr.span("table.build.run"):
+            jax.jit(lambda v: v * 5 + 3)(x).block_until_ready()  # a new function
+    records = tr.recent()
+    children = {r.name for r in records if r.parent == "table.build.run"}
+    assert {"jax.trace", "jax.lower", "jax.compile"} <= children
+    assert [r.name for r in records if r.parent == "plan.join"] == ["table.build.run"]
+    run = next(r for r in records if r.name == "table.build.run")
+    for r in records:
+        if r.parent == "table.build.run":
+            assert run.start <= r.start <= r.end <= run.end + 1e-3
+    snap = tr.registry.snapshot()
+    assert snap.histogram("span_seconds", {"span": "jax.compile"}).count >= 1
+
+
+def test_tracer_span_disabled_records_nothing(annotations):
+    reg = MetricsRegistry()
+    tr = Tracer(reg, enabled=False)
+    with tr.span("plan.join") as span:
+        jax.jit(lambda v: v - 11)(jnp.arange(3)).block_until_ready()
+    with tr.annotate("frontend.dispatch"):
+        pass
+    assert span.seconds >= 0.0  # the instruments that read it still can
+    assert tr.recent() == []
+    assert reg.snapshot().labels_of("span_seconds") == []
+    assert annotations.entered == []
+
+
+def test_table_and_plan_spans_in_the_process_tracer(mesh8, annotations):
+    tracer = process_tracer()
+    assert tracer.enabled is False  # off unless a caller turns it on
+    table = _small_table(mesh8)
+    keys = np.arange(1, 257, dtype=np.uint32)
+    before = len(tracer.recent())
+    tracer.enabled = True
+    try:
+        state = table.init(keys)
+        plan = table.plan_join(num_queries=8, out_capacity=64, seg_capacity=64)
+        plan(state, keys[:8])
+    finally:
+        tracer.enabled = False
+    records = tracer.recent()[before:]
+    parents = {r.name: r.parent for r in records if not r.name.startswith("jax.")}
+    assert parents == {
+        "table.build.pack": "table.build",
+        "table.build.run": "table.build",
+        "table.build": None,
+        "plan.join": None,
+    }
+    # the build's compile happened inside the run span
+    assert any(r.name == "jax.compile" and r.parent == "table.build.run" for r in records)
+    assert annotations.entered[:3] == ["table.build", "table.build.pack", "table.build.run"]
+    n = len(tracer.recent())
+    plan(state, keys[8:16])  # disabled again: nothing recorded
+    assert len(tracer.recent()) == n
+
+
+def test_fold_span_feeds_the_fold_histogram(mesh8, annotations):
+    table = _small_table(mesh8)
+    server = TableServer(
+        table,
+        np.arange(1, 129, dtype=np.uint32),
+        policy=CompactionPolicy(max_delta_depth=2, fold_k=1),
+        write_bucket=8,
+    )
+    tracer = process_tracer()
+    spans = tracer.registry.histogram("span_seconds", labels={"span": "server.fold"})
+    count0, sum0 = spans.snapshot().count, spans.snapshot().sum
+    tracer.enabled = True
+    try:
+        for k in (9991, 9992, 9993):
+            server.submit_insert(np.array([k], dtype=np.uint32))
+            server.step()  # the third step folds first
+    finally:
+        tracer.enabled = False
+    fold = server.metrics().histogram("maintenance_fold_seconds", {"kind": "fold"})
+    span = spans.snapshot()
+    assert fold.count == span.count - count0 == 1
+    assert fold.sum == pytest.approx(span.sum - sum0, rel=1e-12)  # one reading feeds both
+    assert "serve_last_fold_seconds" not in server.metrics().types
+    assert not hasattr(server.stats(), "last_fold_seconds")
 
 
 # ---------------------------------------------------------------------------
