@@ -457,8 +457,11 @@ def csr_gather(
 
     Row ``i`` owns ``table[starts[i] : starts[i]+counts[i]]``; the runs are
     concatenated row-major into a static ``(capacity,)`` buffer (HashGraph's
-    CSR-build idiom applied to the *output*: prefix-sum the counts, then one
-    vectorized gather resolves every output slot).
+    CSR-build idiom applied to the *output*: prefix-sum the counts, expand
+    the rows onto the output slots, then one vectorized gather fills every
+    slot).  The expansion scatters a marker per row at the slot where its
+    run begins and prefix-sums the markers over the slots: O(capacity + N),
+    with no search of the offsets per slot.
 
     Returns ``(offsets, row_idx, gathered, num_dropped)``:
 
@@ -474,18 +477,29 @@ def csr_gather(
       "re-run with a larger capacity".
     """
     counts = counts.astype(jnp.int32)
-    n_rows = counts.shape[0]
     offsets = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)]
     )
     total = offsets[-1]
     slot = jnp.arange(capacity, dtype=jnp.int32)
-    row = jnp.clip(
-        jnp.searchsorted(offsets, slot, side="right").astype(jnp.int32) - 1,
-        0,
-        n_rows - 1,
-    )
-    src = starts.astype(jnp.int32)[row] + (slot - offsets[row])
+    # Slot s belongs to the last row whose offset is <= s, and reads
+    # table[s + shift[row]].  Each row marks the slot where its run begins:
+    # with a row step of 1 (row 0 needs none) and with the change of shift
+    # from the row before.  Prefix sums of the marks give every slot its
+    # row and shift.  Zero-count rows share their successor's slot, so
+    # their steps add up and their shifts telescope away; marks at or past
+    # capacity are dropped, as no slot reads them.  (Two 1-D scatters, not
+    # one of a (2, capacity) operand, which XLA:TPU scatters ~6x slower.)
+    def spread(at, marks):
+        return jnp.cumsum(
+            jnp.zeros((capacity,), jnp.int32)
+            .at[at]
+            .add(marks, mode="drop", indices_are_sorted=True)
+        )
+
+    shift = starts.astype(jnp.int32) - offsets[:-1]
+    row = spread(offsets[1:-1], jnp.int32(1))
+    src = slot + spread(offsets[:-1], jnp.diff(shift, prepend=0))
     valid = slot < total
     tn = table.shape[0]
     # table may carry trailing payload columns (N, C); broadcast the mask.

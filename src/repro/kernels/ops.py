@@ -195,10 +195,11 @@ def csr_gather(
     binary-search + gather runs in the Pallas kernel with ``offsets`` /
     ``starts`` / ``table`` resident in VMEM.  Returns
     ``(offsets, row_idx, gathered, num_dropped)`` — the same contract as
-    ``repro.core.hashgraph.csr_gather`` for 32-bit tables: the kernel moves
-    int32 lanes, so a uint32 ``table`` is bitcast through int32 and restored
-    on output (``fill`` is likewise reinterpreted, e.g. ``-1`` → 0xFFFFFFFF);
-    other dtypes are rejected.
+    ``repro.core.hashgraph.csr_gather`` (which expands the slots by a
+    scatter and a prefix sum, not a search) for 32-bit tables: the kernel
+    moves int32 lanes, so a uint32 ``table`` is bitcast through int32 and
+    restored on output (``fill`` is likewise reinterpreted, e.g. ``-1`` →
+    0xFFFFFFFF); other dtypes are rejected.
 
     Lane-aware: for a multi-column ``(Tn, C)`` table the kernel resolves the
     per-slot binary search once (column 0); the remaining columns reuse the

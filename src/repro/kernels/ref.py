@@ -52,9 +52,10 @@ def csr_gather_ref(
     """Oracle for the CSR gather kernel: ``(values, row_idx)``, each (capacity,).
 
     Lane-aware: a multi-column ``(Tn, C)`` table yields ``(capacity, C)``
-    values.  Deliberately *not* the kernel's searchsorted idiom (that lives
-    in ``repro.core.hashgraph.csr_gather`` too): a plain numpy concatenation
-    of the runs, so a bug in the shared idiom cannot hide in the comparison.
+    values.  Deliberately neither the kernel's per-slot searchsorted nor the
+    scatter-and-prefix-sum expansion of ``repro.core.hashgraph.csr_gather``:
+    a plain numpy concatenation of the runs, so a bug in either cannot hide
+    in the comparison.
     """
     import numpy as np
 

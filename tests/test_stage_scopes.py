@@ -92,6 +92,23 @@ def test_every_entry_instruction_has_a_stage(kind, ndev):
     assert {"route", "locate", "return"} <= seen or kind == "build"
 
 
+@pytest.mark.parametrize("ndev", [1, 8])
+@pytest.mark.parametrize("kind", ["exec_join", "exec_retrieve"])
+def test_no_loop_over_the_output_slots(kind, ndev):
+    """Loops are searches of the probes (route's split search, locate's
+    bucket bisection): gather and expand fill their output slots by a
+    scatter and a prefix sum, with no search loop over every slot."""
+    devices = jax.devices()
+    if len(devices) < ndev:
+        pytest.skip(f"needs {ndev} (fake) devices")
+    text = _compiled_text(kind, devices[:ndev])
+    stages = hlo_stages(text)
+    whiles = re.findall(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=.*\swhile\(", text, re.M)
+    loops = {stages[w] for w in whiles}
+    assert "locate" in loops
+    assert loops <= {"route", "locate"}
+
+
 def test_hlo_stages_takes_the_outermost_scope_and_inherits():
     @jax.jit
     def f(x, y):
