@@ -482,9 +482,43 @@ def contains_sharded(
     return query_sharded(dhg, queries, **kw) > 0
 
 
+# The two all-to-alls of a routed read, in the order of ``exchange_rows``:
+# probe keys out to their owners, result rows back to the queriers.
+EXCHANGE_PHASES = ("route", "return")
+
+
+def exchange_slots(
+    num_queries: int,
+    num_devices: int,
+    capacity_slack: float,
+    seg_capacity: int,
+    rounds: int = 1,
+) -> tuple[int, int]:
+    """Slots one retrieve or join call ships over the whole mesh, per
+    :data:`EXCHANGE_PHASES`: each device sends ``D`` route slots of
+    :func:`default_capacity` keys and ``D`` return segments of
+    ``seg_capacity`` rows, once per exchange round (one round on the fused
+    path, one per layer on the legacy path).  Static: from shapes alone."""
+    n_local = num_queries // num_devices
+    per_device = (
+        num_devices * default_capacity(n_local, num_devices, capacity_slack),
+        num_devices * seg_capacity,
+    )
+    return tuple(rounds * num_devices * x for x in per_device)
+
+
+def _segment_rows(block_counts: jax.Array, seg_capacity: int) -> jax.Array:
+    """Real rows an owner packs into its return segments: each source
+    block's matched rows, up to the segment's ``seg_capacity``."""
+    per_source = jnp.sum(block_counts, axis=1)
+    return jnp.sum(jnp.minimum(per_source, seg_capacity)).astype(jnp.int32)
+
+
 @partial(
     jax.tree_util.register_dataclass,
-    data_fields=("offsets", "values", "counts", "num_dropped", "layer_counts"),
+    data_fields=(
+        "offsets", "values", "counts", "num_dropped", "layer_counts", "exchange_rows"
+    ),
     meta_fields=(),
 )
 @dataclasses.dataclass(frozen=True)
@@ -506,6 +540,13 @@ class ShardRetrieval:
     requested; on the fused path it rides home inside the same single
     all-to-all as the values (the bitcast packing trick of
     ``exchange.combine_ragged``), so requesting it adds no collective round.
+
+    ``exchange_rows`` counts this device's real rows in the two
+    all-to-alls, in :data:`EXCHANGE_PHASES` order: the probe keys it
+    received (``route``) and the result rows it packed for the queriers
+    (``return``), summed over exchange rounds.  Against
+    :func:`exchange_slots` it gives how much of what the exchange ships is
+    padding.  Read it after the calls that matter, never between them.
     """
 
     offsets: jax.Array  # (n_local_queries + 1,) int32
@@ -513,11 +554,12 @@ class ShardRetrieval:
     counts: jax.Array  # (n_local_queries,) int32
     num_dropped: jax.Array  # () int32, global
     layer_counts: Optional[jax.Array] = None  # (n_local_queries, L) int32
+    exchange_rows: Optional[jax.Array] = None  # (1, 2) int32
 
 
 @partial(
     jax.tree_util.register_dataclass,
-    data_fields=("query_idx", "values", "num_results", "num_dropped"),
+    data_fields=("query_idx", "values", "num_results", "num_dropped", "exchange_rows"),
     meta_fields=(),
 )
 @dataclasses.dataclass(frozen=True)
@@ -526,14 +568,15 @@ class ShardJoin:
 
     ``(query_idx[j], values[j])`` for ``j < num_results[0]`` are the match
     pairs produced by this device's queries; ``query_idx`` is the *global*
-    query row id (rank * n_local + local index).  Same ``num_dropped``
-    contract as :class:`ShardRetrieval`.
+    query row id (rank * n_local + local index).  Same ``num_dropped`` and
+    ``exchange_rows`` contracts as :class:`ShardRetrieval`.
     """
 
     query_idx: jax.Array  # (out_capacity,) int32, -1 beyond num_results
     values: jax.Array  # (out_capacity,) int32
     num_results: jax.Array  # (1,) int32 — this device's valid pair count
     num_dropped: jax.Array  # () int32, global
+    exchange_rows: Optional[jax.Array] = None  # (1, 2) int32
 
 
 def _use_kernel_default(use_kernel: Optional[bool]) -> bool:
@@ -581,14 +624,18 @@ def _retrieve_runs(
     launch over all sources on the kernel path — then a reverse all-to-all
     returns segments and run lengths to the querying shard.
 
-    Returns ``(counts, starts, seg_flat, dropped)`` in the querier's local
-    row order: row ``i``'s values are
-    ``seg_flat[starts[i] : starts[i] + counts[i]]``.
+    Returns ``(counts, starts, seg_flat, dropped, rows)`` in the querier's
+    local row order: row ``i``'s values are
+    ``seg_flat[starts[i] : starts[i] + counts[i]]``; ``rows`` is this
+    device's ``(2,)`` real rows of the two exchanges (see
+    :data:`EXCHANGE_PHASES`).
     """
     axis_names = dhg.axis_names
     num_devices = exchange.device_count(axis_names)
 
     rq, route, rbuckets, capacity = _route_queries(dhg, queries, capacity_slack)
+    with stage("route"):
+        routed = jnp.sum(~hashgraph.is_empty_key(rq)).astype(jnp.int32)
     with stage("locate"):
         run_starts, run_counts = hashgraph.query_locate(
             dhg.local, rq, buckets=rbuckets
@@ -619,7 +666,8 @@ def _retrieve_runs(
             seg_values, run_counts, route, axis_names
         )
         dropped = owner_dropped + route.num_dropped
-    return counts, starts, seg_flat, dropped
+        rows = jnp.stack([routed, _segment_rows(counts_b, seg_capacity)])
+    return counts, starts, seg_flat, dropped, rows
 
 
 def _layer_run_descriptors(
@@ -708,6 +756,8 @@ def _retrieve_parts_fused(
     rq, route, rh, is_pad, lo, capacity = _route_queries_once(
         base, queries, capacity_slack
     )
+    with stage("route"):
+        routed = jnp.sum(~is_pad).astype(jnp.int32)
     starts_lr, counts_lr, tables = _layer_run_descriptors(
         layers, rq, rh, is_pad, lo, tombstones
     )
@@ -740,6 +790,8 @@ def _retrieve_parts_fused(
             counts, starts, seg_flat = exchange.combine_ragged(
                 seg_values, slot_totals, route, axis_names
             )
+        returned = _segment_rows(slot_totals.reshape(num_devices, capacity), seg_capacity)
+        rows = jnp.stack([routed, returned])[None]
     with stage("expand"):
         offsets, slot_rows, values, out_dropped = _csr_gather_any(
             starts, counts, seg_flat, out_capacity, use_kernel
@@ -747,7 +799,9 @@ def _retrieve_parts_fused(
         num_dropped = jax.lax.psum(
             owner_dropped + route.num_dropped + out_dropped, axis_names
         )
-    return offsets, slot_rows, values, counts, num_dropped, rank, n_local, layer_breakdown
+    return (
+        offsets, slot_rows, values, counts, num_dropped, rank, n_local, layer_breakdown, rows
+    )
 
 
 def _retrieve_parts(
@@ -803,10 +857,10 @@ def _retrieve_parts(
     n_local = queries.shape[0]
     rank = exchange.my_rank(axis_names)
 
-    counts_l, starts_l, segs_l = [], [], []
+    counts_l, starts_l, segs_l, rows_l = [], [], [], []
     dropped = jnp.int32(0)
     for epoch, layer in enumerate(layers):
-        counts, starts, seg_flat, drop = _retrieve_runs(
+        counts, starts, seg_flat, drop, rows = _retrieve_runs(
             layer,
             queries,
             seg_capacity=seg_capacity,
@@ -815,11 +869,14 @@ def _retrieve_parts(
             tombstones=tombstones,
             layer_epoch=epoch,
         )
+        rows_l.append(rows)
         with stage("expand"):
             counts_l.append(counts)
             starts_l.append(starts + epoch * seg_flat.shape[0])
             segs_l.append(seg_flat)
             dropped = dropped + drop
+    with stage("return"):
+        rows = jnp.sum(jnp.stack(rows_l), axis=0)[None]
 
     with stage("expand"):
         seg_all = segs_l[0] if nlayers == 1 else jnp.concatenate(segs_l, axis=0)
@@ -839,7 +896,9 @@ def _retrieve_parts(
         layer_breakdown = (
             jnp.stack(counts_l, axis=1).astype(jnp.int32) if per_layer else None
         )
-    return offsets, query_idx, values, counts, num_dropped, rank, n_local, layer_breakdown
+    return (
+        offsets, query_idx, values, counts, num_dropped, rank, n_local, layer_breakdown, rows
+    )
 
 
 def retrieve_sharded(
@@ -888,7 +947,7 @@ def retrieve_layers_sharded(
     fused path the planes ride the same single all-to-all as the values.
     Call inside ``shard_map``.
     """
-    offsets, _, values, counts, num_dropped, _, _, layer_counts = _retrieve_parts(
+    offsets, _, values, counts, num_dropped, _, _, layer_counts, rows = _retrieve_parts(
         layers,
         queries,
         seg_capacity=seg_capacity,
@@ -905,6 +964,7 @@ def retrieve_layers_sharded(
         counts=counts,
         num_dropped=num_dropped,
         layer_counts=layer_counts,
+        exchange_rows=rows,
     )
 
 
@@ -946,7 +1006,7 @@ def inner_join_layers_sharded(
 
     Call inside ``shard_map``.
     """
-    _, query_idx, values, counts, num_dropped, rank, n_local, _ = _retrieve_parts(
+    _, query_idx, values, counts, num_dropped, rank, n_local, _, rows = _retrieve_parts(
         layers,
         queries,
         seg_capacity=seg_capacity,
@@ -965,6 +1025,7 @@ def inner_join_layers_sharded(
         values=values,
         num_results=num_results,
         num_dropped=num_dropped,
+        exchange_rows=rows,
     )
 
 

@@ -210,6 +210,7 @@ def exec_retrieve(
         counts=P(ax),
         num_dropped=P(),
         layer_counts=P(ax) if per_layer_counts else None,
+        exchange_rows=P(ax),
     )
 
     def body(st, q):
@@ -248,7 +249,11 @@ def exec_join(
     """Materialized inner join over the versioned stack."""
     ax = tuple(table.axis_names)
     out_specs = ShardJoin(
-        query_idx=P(ax), values=P(ax), num_results=P(ax), num_dropped=P()
+        query_idx=P(ax),
+        values=P(ax),
+        num_results=P(ax),
+        num_dropped=P(),
+        exchange_rows=P(ax),
     )
 
     def body(st, q):
@@ -416,6 +421,25 @@ def _proto_queries(table, num_queries: int) -> jax.Array:
 
 
 class _PlanBase:
+    def _count_exchange_slots(self, st, q) -> None:
+        """Add this call's exchange slots (static, from shapes) to the
+        process registry's ``exchange_slots_total{phase}``; a call traced
+        inside an outer ``jax.jit`` counts nothing."""
+        if isinstance(q, jax.core.Tracer):
+            return
+        table = self.table
+        rounds = 1 if _fused(table, st) else len(st.layers)
+        slots = multi_hashgraph.exchange_slots(
+            q.shape[0], table.num_devices, table.capacity_slack, self.seg_capacity, rounds
+        )
+        registry = process_tracer().registry
+        for phase, n in zip(multi_hashgraph.EXCHANGE_PHASES, slots):
+            registry.counter(
+                "exchange_slots_total",
+                labels={"phase": phase},
+                help="Slots shipped by the all-to-alls of retrieve and join plan calls.",
+            ).inc(n)
+
     def _prep(self, state, queries):
         st = as_state(self.table, state)
         q = self.table.schema.pack_keys(queries)
@@ -487,6 +511,7 @@ class RetrievePlan(_PlanBase):
     def __call__(self, state, queries) -> ShardRetrieval:
         with process_tracer().span("plan.retrieve"):
             st, q = self._prep(state, queries)
+            self._count_exchange_slots(st, q)
             return exec_retrieve(
                 self.table,
                 st,
@@ -533,6 +558,7 @@ class JoinPlan(_PlanBase):
     def __call__(self, state, queries) -> ShardJoin:
         with process_tracer().span("plan.join"):
             st, q = self._prep(state, queries)
+            self._count_exchange_slots(st, q)
             return exec_join(
                 self.table,
                 st,

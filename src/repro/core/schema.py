@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.hashgraph import EMPTY_KEY
+
 _KEY_DTYPES = ("uint32", "uint64")
 
 
@@ -51,13 +53,29 @@ def _pack_u64_host(keys) -> np.ndarray:
     return np.stack([lo, hi], axis=-1)
 
 
-def _place(x, sharding):
-    """A device array of ``x``: on ``sharding`` when given, else the default
-    device.  Host arrays are transferred once, straight to their shards;
-    traced values are left to the enclosing program's shardings."""
+def _place(x, sharding, pad: int = 0, fill=0):
+    """A device array of ``x`` with ``pad`` rows of ``fill`` appended: on
+    ``sharding`` when given, else the default device.  Host arrays are
+    transferred once, straight to their shards (a padded one shard by shard,
+    so only the shards that hold padding are copied on the host); traced
+    values are left to the enclosing program's shardings."""
+    if pad and (sharding is None or not isinstance(x, np.ndarray)):
+        x = jnp.concatenate([jnp.asarray(x), jnp.full((pad,) + x.shape[1:], fill, x.dtype)])
     if sharding is None or isinstance(x, jax.core.Tracer):
         return jnp.asarray(x)
-    return jax.device_put(x, sharding)
+    if not pad or not isinstance(x, np.ndarray):
+        return jax.device_put(x, sharding)
+    n = x.shape[0]
+
+    def shard(index):
+        start, stop, _ = index[0].indices(n + pad)
+        part = x[start : min(stop, n)]
+        if stop <= n:
+            return part
+        tail = np.full((stop - max(start, n),) + x.shape[1:], fill, x.dtype)
+        return np.concatenate([part, tail])
+
+    return jax.make_array_from_callback((n + pad,) + x.shape[1:], sharding, shard)
 
 
 def unpack_u64(packed) -> np.ndarray:
@@ -115,20 +133,21 @@ class TableSchema:
         self._check_key_shape(keys.shape)
         return keys
 
-    def pack_keys(self, keys, sharding=None) -> jax.Array:
+    def pack_keys(self, keys, sharding=None, pad: int = 0) -> jax.Array:
         """Canonical device layout: ``(N,)`` uint32 or ``(N, 2)`` uint32.
 
         Accepts host arrays (canonicalized by :meth:`host_keys`) or
         already-packed device arrays; validates the lane count.  With
         ``sharding`` the result is placed on it (host rows go straight to
-        their shards, never through one device).
+        their shards, never through one device).  ``pad`` appends that many
+        ``EMPTY_KEY`` sentinel rows.
         """
         if isinstance(keys, jax.Array):
             keys = keys.astype(jnp.uint32)
             self._check_key_shape(keys.shape)
         else:
             keys = self.host_keys(keys)
-        return _place(keys, sharding)
+        return _place(keys, sharding, pad, EMPTY_KEY)
 
     def _check_key_shape(self, shape) -> None:
         if self.key_lanes == 1:
@@ -144,14 +163,15 @@ class TableSchema:
         """Canonical payload on the host: ``(N,)`` or ``(N, C)`` int32."""
         return self._check_values(np.asarray(values).astype(np.int32))
 
-    def pack_values(self, values, sharding=None) -> jax.Array:
+    def pack_values(self, values, sharding=None, pad: int = 0) -> jax.Array:
         """Canonical payload layout: ``(N,)`` or ``(N, C)`` int32, placed on
-        ``sharding`` when given (see :meth:`pack_keys`)."""
+        ``sharding`` when given, with ``pad`` rows of -1 appended (see
+        :meth:`pack_keys`)."""
         if isinstance(values, jax.Array):
             values = self._check_values(values.astype(jnp.int32))
         else:
             values = self.host_values(values)
-        return _place(values, sharding)
+        return _place(values, sharding, pad, -1)
 
     def default_values(self, n: int) -> np.ndarray:
         """Row-id payload for ``n`` rows, repeated across every column."""

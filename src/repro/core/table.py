@@ -238,9 +238,13 @@ class DistributedHashTable:
         """Build a (build-once) distributed graph from a global key array.
 
         ``keys``: ``(N,)`` uint32 for the 1-lane schema, ``(N, 2)`` packed
-        uint32 (``schema.pack_u64``) for uint64; ``N % devices == 0``.
+        uint32 (``schema.pack_u64``) for uint64; any ``N``.
         ``values``: optional ``(N,)`` / ``(N, C)`` int32 payload matching
         ``schema.value_cols`` (default: global row ids, 1-column only).
+        When ``N`` is not a multiple of the devices, the rows are padded to
+        one with ``EMPTY_KEY`` sentinels (values -1), as ``upsert`` pads:
+        they land in the owners' trash buckets, match no key, and count
+        neither as live rows nor as dropped ones.
 
         .. deprecated:: use :meth:`init`, which returns a versioned
            :class:`TableState` supporting insert/delete/compact.  ``build``
@@ -253,11 +257,12 @@ class DistributedHashTable:
             )
         tracer = process_tracer()
         sharding = self.key_sharding()
+        pad = -len(keys) % self.num_devices
         with tracer.span("table.build"):
             with tracer.span("table.build.pack"):
-                keys = self.schema.pack_keys(keys, sharding)
+                keys = self.schema.pack_keys(keys, sharding, pad)
                 if values is not None:
-                    values = self.schema.pack_values(values, sharding)
+                    values = self.schema.pack_values(values, sharding, pad)
             # Launch to ready; only a traced build waits for the device here.
             with tracer.span("table.build.run"):
                 if values is None:
@@ -272,6 +277,8 @@ class DistributedHashTable:
 
     def init(self, keys, values=None) -> TableState:
         """Build and wrap into a versioned :class:`TableState`.
+
+        ``keys`` and ``values`` follow :meth:`build`: any row count.
 
         The state starts with an empty delta ring and a zero-capacity
         tombstone buffer (pure-read states pay no masking cost); the buffer
@@ -531,10 +538,10 @@ class DistributedHashTable:
     ) -> TableState:
         """Functional insert: a new state with one more delta graph.
 
-        ``keys``/``values`` follow the :meth:`build` contract (global
-        arrays, ``N % devices == 0``).  Raises when the delta ring is full —
-        call :meth:`compact` first, or pass ``auto_compact=True`` to fold
-        the state automatically whenever
+        ``keys``/``values`` are global arrays with ``N % devices == 0``
+        (unlike :meth:`build`, insert does not pad).  Raises when the delta
+        ring is full — call :meth:`compact` first, or pass
+        ``auto_compact=True`` to fold the state automatically whenever
         :meth:`~repro.core.state.TableState.should_compact` fires (ring
         full, tombstone load, or tombstone overflow; host-syncing — eager
         use only).  With ``values=None`` the default payload is the row id
@@ -705,13 +712,8 @@ class DistributedHashTable:
             return st
         real = keys  # unpadded: tombstoning EMPTY pads would burn slots
         pad = (-keys.shape[0]) % self.num_devices
-        if pad:
-            keys = jnp.concatenate(
-                [keys, jnp.full((pad,) + keys.shape[1:], EMPTY_KEY, jnp.uint32)]
-            )
-            values = jnp.concatenate(
-                [values, jnp.full((pad,) + values.shape[1:], -1, jnp.int32)]
-            )
+        keys = self.schema.pack_keys(keys, pad=pad)
+        values = self.schema.pack_values(values, pad=pad)
         st = self.delete(st, real)  # hide prior versions: epoch d
         st = self.insert(st, keys, values)  # the new version: epoch d + 1
         if ttl is not None:

@@ -41,7 +41,6 @@ import time
 from collections import deque
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -113,7 +112,7 @@ class TableServer:
                     "write_bucket must be a multiple of the device count"
                 )
             self.write_bucket = wb
-        state = table.init(*self._pad_insert(keys, values))
+        state = table.init(*self._host_rows(keys, values))
         if self.write_bucket is not None:
             # Shape-stable serving pre-grows the tombstone buffer (init
             # leaves it at zero capacity until the first delete): one
@@ -185,7 +184,7 @@ class TableServer:
     def _pad_insert(self, keys, values, bucket: Optional[int] = None):
         """Device-align one mutation batch: EMPTY-pad keys, -1-pad values.
 
-        The build/insert contract wants ``N % devices == 0``; sentinel rows
+        The insert contract wants ``N % devices == 0``; sentinel rows
         route round-robin, land in trash buckets, and are invisible to
         every read — the same padding idiom as the exchange.  With
         ``bucket`` the batch is padded all the way to that fixed size, so
@@ -196,13 +195,8 @@ class TableServer:
         keys, values = self._host_rows(keys, values)
         n = keys.shape[0]
         pad = (-n) % self.table.num_devices if bucket is None else bucket - n
-        if pad:
-            kshape = (pad,) + keys.shape[1:]
-            vshape = (pad,) + values.shape[1:]
-            keys = np.concatenate([keys, np.full(kshape, EMPTY_KEY, np.uint32)])
-            values = np.concatenate([values, np.full(vshape, -1, np.int32)])
-        sharding = self.table.key_sharding()
-        return jax.device_put(keys, sharding), jax.device_put(values, sharding)
+        schema, sharding = self.table.schema, self.table.key_sharding()
+        return schema.pack_keys(keys, sharding, pad), schema.pack_values(values, sharding, pad)
 
     def submit_insert(self, keys, values=None) -> None:
         """Queue one insert batch (applied by the writer loop).

@@ -24,6 +24,9 @@ from repro.core.state import TableState, empty_tombstones
 from repro.core.table import DistributedHashTable, table_mesh
 
 ROWS_PER_CHIP = 1 << 25  # chip_smoke.py's one-chip table
+TPCH_HOST_ROWS = 149_999_997  # join-probe-tpch-4chip: a quarter of SF 100's lineitems
+TPCH_PROBES_PER_CHIP = 1 << 17
+TPCH_OUT = 7 << 17  # out_capacity and seg_capacity of that cell, per device
 WRITE_BUCKET = 512
 TOMBSTONES = 4096
 HBM_BYTES = 16 * 10**9  # one v5e chip
@@ -143,3 +146,38 @@ def test_four_chip_retrieve_has_two_all_to_alls(topo):
     ).compile()
     assert compiled.as_text().count("all-to-all(") == 2
     _fits_one_chip(compiled)
+
+
+def test_four_chip_tpch_join_fits_with_two_all_to_alls(topo):
+    """``exec_join`` of join-probe-tpch-4chip at its size: the build's
+    149,999,997 lineitems padded to 37.5M rows a chip, u32 keys and 4
+    columns, probed with 2^17 keys a chip; the probes out and the rows
+    back are its only all-to-alls."""
+    mesh = table_mesh(topo.devices)
+    table = DistributedHashTable(
+        mesh, ("d",), hash_range=TPCH_HOST_ROWS, schema=TableSchema("uint32", 4),
+        use_kernel=False,
+    )
+    padded = TPCH_HOST_ROWS + (-TPCH_HOST_ROWS % 4)
+    sharding = table.key_sharding()
+    base = jax.eval_shape(
+        lambda k, v: table._build_values_jit(k, v, hash_range=TPCH_HOST_ROWS),
+        jax.ShapeDtypeStruct((padded,), jnp.uint32, sharding=sharding),
+        jax.ShapeDtypeStruct((padded, 4), jnp.int32, sharding=sharding),
+    )
+    state = TableState(
+        base=base, deltas=(), tombstones=jax.eval_shape(lambda: empty_tombstones(0, 1)),
+        table=table,
+    )
+    state = jax.tree.map(
+        lambda x, spec: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=NamedSharding(mesh, spec)),
+        state,
+        plans.state_specs(state),
+    )
+    queries = jax.ShapeDtypeStruct((4 * TPCH_PROBES_PER_CHIP,), jnp.uint32, sharding=sharding)
+    compiled = plans.exec_join.lower(
+        table, state, queries, out_capacity=TPCH_OUT, seg_capacity=TPCH_OUT
+    ).compile()
+    assert compiled.as_text().count("all-to-all(") == 2
+    _fits_one_chip(compiled)
+
